@@ -154,8 +154,12 @@ def _append_history(path: str, record: dict, *,
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
     from . import (detection_overhead, error_propagation, recovery,
                    roofline_table, serving, transport_latency)
+
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json", nargs="?", const="BENCH_serving.json",
@@ -196,17 +200,21 @@ def main() -> None:
         sections = [(n, f) for n, f in sections if n == args.only]
         if not sections:
             raise SystemExit(f"unknown section: {args.only}")
+    failed = []
     for name, fn in sections:
         try:
             for row_name, derived, us in fn():
                 print(f"{row_name},{us:.2f},{derived}")
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report every section, then fail
+            failed.append(name)
             print(f"{name}_FAILED,0,{type(e).__name__}:{e}", file=sys.stderr)
             print(f"{name}_FAILED,0,0")
     if args.json and serving_record:
         _append_history(args.json, serving_record,
                         allow_dirty=args.allow_dirty)
         print(f"appended run to {args.json}", file=sys.stderr)
+    if failed:
+        raise SystemExit(f"failed sections: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -180,9 +180,9 @@ PAGED_MIXED_PROMPTS = (16, 1024, 32, 48, 64, 128, 16, 256, 32, 512, 24, 96)
 PAGED_MAX_NEW = 16
 
 # --- tensor-parallel cells (ISSUE 9): the tp=2 engine on the qwen3 smoke
-# config (the arch the TP test suite shards), steady + faulted. Skipped —
-# loudly, in the record — when fewer than TP devices are visible; CI forces
-# host devices so the cells always ride the tracked history there.
+# config (the arch the TP test suite shards), steady + faulted. Fewer than
+# TP visible devices is an error, never a skip; on the CPU, force host
+# devices (XLA_FLAGS=--xla_force_host_platform_device_count=TP).
 TP = 2
 TP_ARCH = "qwen3-1.7b"
 TP_ENGINE = (f"window{WINDOW}_tp{TP}",
@@ -539,16 +539,14 @@ def bench_all():
               for engine, engine_kw in SPEC_ENGINES
               for label, fault_every in (("steady", 0),
                                          ("faulted", FAULT_EVERY))]
-    tp_ok = len(jax.devices()) >= TP
-    record["tp_skipped"] = not tp_ok
-    if tp_ok:
-        cells += [(TP_ENGINE[0], TP_ENGINE[1], label, fault_every, TP_RUN_KW)
-                  for label, fault_every in (("steady", 0),
-                                             ("faulted", FAULT_EVERY))]
-    else:
-        print(f"# tp cells skipped: {len(jax.devices())} device(s) < tp={TP} "
-              "(set XLA_FLAGS=--xla_force_host_platform_device_count="
-              f"{TP})")
+    if len(jax.devices()) < TP:
+        raise RuntimeError(
+            f"the tp={TP} cells need {TP} devices, found "
+            f"{len(jax.devices())} (on the CPU, set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={TP})")
+    cells += [(TP_ENGINE[0], TP_ENGINE[1], label, fault_every, TP_RUN_KW)
+              for label, fault_every in (("steady", 0),
+                                         ("faulted", FAULT_EVERY))]
     best: dict[str, dict] = {}
     for trial in range(max(N_TRIALS, N_TRIALS_FAULTED)):
         for engine, engine_kw, label, fault_every, run_kw in cells:
@@ -1070,7 +1068,17 @@ def smoke_multihost(out_path: str | None = None,
                           max_new_tokens=12) for i in range(n)]
     engine = EngineConfig(num_slots=2, max_len=32)
 
-    # in-process reference: same arch/seed/engine as every worker process
+    # --- SIGKILL leg: real replicas across real process boundaries
+    sup = MultiHostSupervisor(3, backend="replica", arch=arch, config=engine,
+                              suspect_timeout=suspect_timeout,
+                              heartbeat_interval=0.05, trace=True,
+                              ledger_path=ledger_path, timeout=180.0)
+    res = sup.serve(mk(), faults=FaultSchedule(
+        [FaultSpec(step=2, kind="host_kill", rank=1)]))
+
+    # in-process reference: same arch/seed/engine as every worker process,
+    # built only once the workers have exited (on an accelerator the first
+    # process to touch JAX holds the chip)
     from repro.models import build_model
     cfg = smoke_config(arch)
     params = build_model(cfg).init(jax.random.PRNGKey(0))
@@ -1084,14 +1092,6 @@ def smoke_multihost(out_path: str | None = None,
         steps += 1
         assert steps < 2000
     assert sorted(ref) == list(range(n))
-
-    # --- SIGKILL leg: real replicas across real process boundaries
-    sup = MultiHostSupervisor(3, backend="replica", arch=arch, config=engine,
-                              suspect_timeout=suspect_timeout,
-                              heartbeat_interval=0.05, trace=True,
-                              ledger_path=ledger_path, timeout=180.0)
-    res = sup.serve(mk(), faults=FaultSchedule(
-        [FaultSpec(step=2, kind="host_kill", rank=1)]))
     assert sorted(res.responses) == list(range(n)), (
         "dropped requests across the host loss")
     assert all(r.ok for r in res.responses.values())
@@ -1153,6 +1153,9 @@ def smoke_multihost(out_path: str | None = None,
 if __name__ == "__main__":
     import sys
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if "--smoke" in sys.argv:
         if "--overlap" in sys.argv:
             smoke_overlap()

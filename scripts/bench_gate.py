@@ -3,10 +3,8 @@
 
 Compares the latest recorded serving run against the BEST of the last three
 earlier runs for each engine × scenario cell — the tensor-parallel
-``window8_tp2`` cells included, whenever the run carried them (a run on a
-single-device box records ``tp_skipped`` and those cells simply drop out of
-the comparison, loudly) — plus the paged-capacity, tracer-overhead and
-elastic-group cells, when carried, and fails — exit 1 — if tokens/s dropped
+``window8_tp2`` cells included, whenever the run carried them — plus the
+paged-capacity, tracer-overhead and elastic-group cells, when carried, and fails — exit 1 — if tokens/s dropped
 by more than the threshold (default 15%). Comparing against the best-of-3 baseline (not just
 the single previous run) means one noisy-but-green draw cannot ratchet the
 baseline down: a slow-but-passing run N doesn't lower the bar run N+1 must
@@ -91,9 +89,6 @@ def gate(history_path: str, max_regress: float) -> int:
     if not latest_cells:
         print("bench gate: latest run carries no comparable cells — skipping")
         return 0
-    if latest.get("tp_skipped"):
-        print("bench gate: latest run skipped the tensor-parallel cells "
-              "(single-device box) — window8_tp2 is not being compared")
     # baseline = the 3 most recent earlier runs sharing at least one cell
     # with the latest; each cell is judged against its best value among them
     baseline_runs = []

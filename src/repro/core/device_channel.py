@@ -132,11 +132,6 @@ def make_enumerate_fn(mesh: jax.sharding.Mesh, axis_name: str,
     """
     from jax.sharding import PartitionSpec as P
 
-    try:                                   # jax >= 0.5
-        shard_map = jax.shard_map
-    except AttributeError:                 # jax 0.4.x
-        from jax.experimental.shard_map import shard_map
-
     n = mesh.shape[axis_name]
 
     def body(words):
@@ -144,7 +139,7 @@ def make_enumerate_fn(mesh: jax.sharding.Mesh, axis_name: str,
             words[0], axis_name=axis_name, n=n, max_errors=max_errors)
         return count[None], table[None]
 
-    mapped = shard_map(body, mesh=mesh, in_specs=P(axis_name),
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=P(axis_name),
                        out_specs=(P(axis_name), P(axis_name, None, None)))
 
     @jax.jit
@@ -193,7 +188,7 @@ class DeviceFuture:
         word_arr = self.word
         if timeout is not None:
             deadline = time.monotonic() + timeout
-            while not _is_ready(word_arr):
+            while not word_arr.is_ready():
                 if time.monotonic() > deadline:
                     raise TimeoutError_(f"device step exceeded {timeout}s "
                                         "(straggler watchdog)")
@@ -217,7 +212,7 @@ class DeviceFuture:
         without blocking. Lets a serving loop distinguish a device-bound
         pipeline (the window is still computing at retirement) from a
         host-bound one without perturbing async dispatch."""
-        return self._waited or _is_ready(self.word)
+        return self._waited or self.word.is_ready()
 
     def fault_steps(self, *, ignore: int = 0) -> Optional[np.ndarray]:
         """Per-rank index of the first faulting window step, or -1 if clean.
@@ -267,11 +262,3 @@ class DeviceFuture:
         if not errs and word:
             errs = [RankError(rank=-1, code=word)]
         return errs
-
-
-def _is_ready(arr: jax.Array) -> bool:
-    try:
-        return arr.is_ready()  # jax >= 0.4.x on most backends
-    except AttributeError:  # pragma: no cover - fallback
-        jax.block_until_ready(arr)
-        return True

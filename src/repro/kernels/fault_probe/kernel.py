@@ -10,9 +10,10 @@ Design for TPU:
 * the stream is reshaped to ``(rows, 128)`` (lane-aligned) by ``ops.py``;
 * the grid walks row-blocks of ``block_rows`` (8-aligned, sublane-friendly);
 * each grid step computes ``any(!isfinite)`` and ``any(|x| > threshold)`` on the VPU
-  and bitwise-ORs the encoded word into a (1,1) accumulator block that every grid
+  and bitwise-ORs the encoded word into a one-word accumulator that every grid
   step maps to (TPU grid steps execute sequentially on a core, so the accumulation
-  is race-free; the same property holds in interpret mode).
+  is race-free; the same property holds in interpret mode). The threshold and the
+  accumulator live in SMEM: Mosaic cannot store a scalar to VMEM.
 """
 from __future__ import annotations
 
@@ -21,6 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Error-code bits are passed in as static ints to keep the kernel independent of the
 # errors module (and the lattice usable from any layer).
@@ -28,17 +30,19 @@ from jax.experimental import pallas as pl
 
 def _probe_kernel(x_ref, thresh_ref, o_ref, *, nonfinite_code: int,
                   overflow_code: int):
-    i = pl.program_id(0)
     x = x_ref[...].astype(jnp.float32)
-    thresh = thresh_ref[0, 0]
-    nonfinite = jnp.any(jnp.logical_not(jnp.isfinite(x)))
+    finite = jnp.isfinite(x)
+    nonfinite = jnp.any(jnp.logical_not(finite))
     # overflow check must ignore non-finite lanes (inf would always trip it)
-    finite_x = jnp.where(jnp.isfinite(x), x, 0.0)
-    over = jnp.any(jnp.abs(finite_x) > thresh)
+    over = jnp.any(jnp.abs(jnp.where(finite, x, 0.0)) > thresh_ref[0])
     word = (jnp.where(nonfinite, jnp.uint32(nonfinite_code), jnp.uint32(0))
             | jnp.where(over, jnp.uint32(overflow_code), jnp.uint32(0)))
-    prev = jnp.where(i == 0, jnp.uint32(0), o_ref[0, 0])
-    o_ref[0, 0] = prev | word
+
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        o_ref[0] = jnp.uint32(0)
+
+    o_ref[0] = o_ref[0] | word
 
 
 def probe_rows(x: jax.Array, threshold: jax.Array, *, nonfinite_code: int,
@@ -47,18 +51,17 @@ def probe_rows(x: jax.Array, threshold: jax.Array, *, nonfinite_code: int,
     """Probe a ``(rows, 128)`` array; returns a scalar uint32 word."""
     rows, lanes = x.shape
     assert lanes == 128 and rows % block_rows == 0, (rows, lanes, block_rows)
-    grid = (rows // block_rows,)
     kernel = functools.partial(_probe_kernel, nonfinite_code=nonfinite_code,
                                overflow_code=overflow_code)
     out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, 128), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.uint32),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+        out_shape=jax.ShapeDtypeStruct((1,), jnp.uint32),
         interpret=interpret,
-    )(x, threshold.reshape(1, 1).astype(jnp.float32))
-    return out[0, 0]
+    )(x, jnp.reshape(threshold, (1,)).astype(jnp.float32))
+    return out[0]

@@ -22,13 +22,17 @@ def ssd_scan(x, dt, A, B, C, chunk: int = 128):
     nc = s // L
     xf = x.astype(jnp.float32)
     dtf = dt.astype(jnp.float32)
-    xd = (xf * dtf[..., None]).reshape(b, nc, L, h, p)
+    # kernel layout: heads before the chunk axis (see kernel.py)
+    xd = (xf * dtf[..., None]).reshape(b, nc, L, h, p).transpose(0, 1, 3, 2, 4)
     abar = (dtf * A).reshape(b, nc, L, h)
     Bc = jnp.repeat(B, rep, axis=2).astype(jnp.float32).reshape(b, nc, L, h, n)
     Cc = jnp.repeat(C, rep, axis=2).astype(jnp.float32).reshape(b, nc, L, h, n)
 
-    y_diag, states = ssd_intra_chunk(xd, abar, Bc, Cc,
-                                     interpret=_use_interpret())
+    y_diag, states = ssd_intra_chunk(
+        xd, abar.transpose(0, 1, 3, 2)[:, :, :, None, :],
+        Bc.transpose(0, 1, 3, 2, 4), Cc.transpose(0, 1, 3, 2, 4),
+        interpret=_use_interpret())
+    y_diag = y_diag.transpose(0, 1, 3, 2, 4)             # (b,nc,L,h,p)
 
     # inter-chunk recurrence (tiny, sequential)
     cum = jnp.cumsum(abar, axis=2)                       # (b,nc,L,h)

@@ -23,10 +23,13 @@ from pathlib import Path
 import jax
 
 from ..configs import SHAPES, ARCHS, cell_skip_reason, get_config
-from ..roofline.analysis import RooflineTerms, model_flops_for
+from ..roofline.analysis import RooflineTerms, model_flops_for, peaks_for
 from ..roofline.hlo import estimate_hbm_bytes, op_histogram, parse_collectives
 from .mesh import make_production_mesh
 from .steps import BASELINE, PerfOptions, input_specs, make_step_for
+
+# the chip the production meshes are planned for (``launch/mesh.py``)
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def _compile_variant(cfg, shape, mesh, impl, *, inner_unroll: bool = False,
@@ -67,8 +70,6 @@ def _compile_variant(cfg, shape, mesh, impl, *, inner_unroll: bool = False,
         moe_mod.EXPERT_SPEC = prev_espec
         steps_mod.MB_UNROLL = prev_mb
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):      # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = parse_collectives(hlo)
     return compiled, cost, coll, hlo
@@ -131,6 +132,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
             hlo_flops_per_device=flops,
             hlo_bytes_per_device=bytes_,
             collective_bytes_per_device=coll_bytes,
+            peaks=peaks_for(TARGET_DEVICE_KIND),
             model_flops=model_flops_for(cfg, shape),
         )
         rec.update(

@@ -9,15 +9,12 @@ from __future__ import annotations
 
 import jax
 
-try:                                  # jax >= 0.5: explicit axis types exist,
-    from jax.sharding import AxisType  # pin ours to Auto (GSPMD decides)
+from jax.sharding import AxisType
 
-    def _mesh(shape, axes):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-except ImportError:                   # jax 0.4.x: Auto is the only behaviour
-    def _mesh(shape, axes):
-        return jax.make_mesh(shape, axes)
+
+def _mesh(shape, axes):
+    # Auto axes: GSPMD decides how intermediates are laid out
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

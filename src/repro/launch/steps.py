@@ -295,14 +295,6 @@ def _paged_slot_step(slot_step, paged):
 TP_AXIS = "model"
 
 
-def _get_shard_map():
-    try:
-        return jax.shard_map            # public API on newer jax
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-        return shard_map
-
-
 @_dataclass(frozen=True)
 class TPContext:
     """Everything a window factory needs to shard itself over a "model" axis.
@@ -373,7 +365,6 @@ def _tp_window(body, tp: TPContext, *, n_rest: int, words_index: int,
     no shard can diverge from its peers' recovery decision (the TP analogue
     of "no rank deadlocks waiting for a peer that already failed").
     """
-    shard_map = _get_shard_map()
     size = tp.size
 
     def tp_body(params, caches, *rest_and_inj):
@@ -392,11 +383,7 @@ def _tp_window(body, tp: TPContext, *, n_rest: int, words_index: int,
     in_specs = ((tp.param_specs, tp.cache_specs) + (P(),) * n_rest
                 + (P(TP_AXIS),))
     out_specs = (P(),) * (n_out - 1) + (tp.cache_specs,)
-    try:
-        mapped = shard_map(tp_body, mesh=tp.mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
-    except TypeError:   # newer jax renamed the replication-check kwarg
-        mapped = shard_map(tp_body, mesh=tp.mesh, in_specs=in_specs,
+    mapped = jax.shard_map(tp_body, mesh=tp.mesh, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)
     return jax.jit(mapped, donate_argnums=(1,) if donate else ())
 

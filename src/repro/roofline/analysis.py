@@ -1,4 +1,4 @@
-"""Three-term roofline from compiled dry-run artifacts (TPU v5e targets).
+"""Three-term roofline from compiled dry-run artifacts.
 
     compute    = HLO_FLOPs_global / (chips × peak_FLOP/s)
     memory     = HLO_bytes_global / (chips × HBM_bw)
@@ -11,15 +11,45 @@
 ``cost_analysis()`` on the SPMD executable reports *per-device* FLOPs/bytes; we
 scale by chip count for the global numerators, so the terms are per-device times —
 directly comparable to a per-step wall clock.
+
+Peaks come from :data:`PEAKS`, keyed by ``device_kind`` as JAX reports it
+(``jax.devices()[0].device_kind``); a kind that is not in the table is an
+error, never a default.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_LINK_BW = 50e9              # B/s per link (assignment constant)
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops_bf16: float        # FLOP/s
+    hbm_bw: float            # B/s
+    ici_link_bw: float       # B/s per chip-to-chip link
+    source: str
+
+
+#: device_kind -> peaks. JAX names a v5e chip "TPU v5 lite".
+PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        flops_bf16=197e12, hbm_bw=819e9,
+        # 1,600 Gbit/s of interconnect per chip over 4 links
+        ici_link_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e" (per chip: 197 '
+               'TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s '
+               'interconnect)'),
+}
+
+
+def peaks_for(device_kind: str) -> DevicePeaks:
+    """The peaks of ``device_kind``; raises ``KeyError`` for an unknown kind."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)})") from None
 
 
 @dataclass
@@ -28,19 +58,20 @@ class RooflineTerms:
     hlo_flops_per_device: float
     hlo_bytes_per_device: float
     collective_bytes_per_device: float
+    peaks: DevicePeaks
     model_flops: float = 0.0     # 6·N·D (or 6·N_active·D)
 
     @property
     def compute_s(self) -> float:
-        return self.hlo_flops_per_device / PEAK_FLOPS_BF16
+        return self.hlo_flops_per_device / self.peaks.flops_bf16
 
     @property
     def memory_s(self) -> float:
-        return self.hlo_bytes_per_device / HBM_BW
+        return self.hlo_bytes_per_device / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes_per_device / ICI_LINK_BW
+        return self.collective_bytes_per_device / self.peaks.ici_link_bw
 
     @property
     def dominant(self) -> str:
@@ -64,12 +95,13 @@ class RooflineTerms:
         """Fraction of the dominant-term roofline that *useful* model FLOPs
         represent: (MODEL_FLOPS/(chips·peak)) / bound_s. 1.0 = the step is exactly
         as long as the useful math at peak — the hillclimb score."""
-        useful_s = self.model_flops / (self.chips * PEAK_FLOPS_BF16)
+        useful_s = self.model_flops / (self.chips * self.peaks.flops_bf16)
         return useful_s / self.bound_s if self.bound_s else 0.0
 
     def to_dict(self) -> dict:
         return {
             "chips": self.chips,
+            "peaks_source": self.peaks.source,
             "hlo_flops_per_device": self.hlo_flops_per_device,
             "hlo_bytes_per_device": self.hlo_bytes_per_device,
             "collective_bytes_per_device": self.collective_bytes_per_device,

@@ -150,6 +150,7 @@ class RankReport:
     rounds: int = 0
     events: list = field(default_factory=list)   # ("shrink"|"propagated", round, info)
     metrics: Optional[ServeMetrics] = None
+    device: Optional[str] = None                 # where the replica ran
 
 
 @dataclass
@@ -258,6 +259,15 @@ class ServeGroup:
         self.trace_sample = float(config.trace_sample)
         donate = config.donate
         self.params = build_model(cfg).init(jax.random.PRNGKey(seed))
+        # one chip per rank where there are enough: rank r serves from
+        # jax.devices()[r % n], with its own copy of the params (a tp>1 rank
+        # spans the model mesh instead)
+        devices = jax.devices()
+        self._devices = [devices[r % len(devices)]
+                         for r in range(self.max_ranks)]
+        self._rank_params = ({} if self.tp > 1 else
+                             {d: jax.device_put(self.params, d)
+                              for d in dict.fromkeys(self._devices)})
         # compile once, share across rank threads (jit dispatch is thread-safe)
         # — each paged replica owns its own pool + table, but the layout (and
         # therefore every jitted program) is identical across the fleet
@@ -326,6 +336,11 @@ class ServeGroup:
         return TPContext(mesh=mesh,
                          param_specs=param_specs(self.params, mesh),
                          cache_specs=cspecs)
+
+    def device_of(self, rank: int):
+        """The device rank ``rank``'s replica serves from (None when tp>1:
+        the rank spans the model mesh)."""
+        return None if self.tp > 1 else self._devices[rank]
 
     # ------------------------------------------------------------ entry points
     def serve(self, requests: Sequence[Request], *,
@@ -446,7 +461,9 @@ class ServeGroup:
             queue = RequestQueue(AdmissionPolicy(
                 max_queue=10_000, max_total_len=pool_cap), tracer=tracer)
             return Replica(
-                self.cfg, params=self.params, config=self.config,
+                self.cfg, params=(self.params if self.tp > 1 else
+                                  self._rank_params[self._devices[rank]]),
+                config=self.config,
                 queue=queue, rank=rank,
                 decode_fn=self._decode_fn, prefill_fn=self._prefill_fn,
                 window_fn=self._window_fn, paged_layout=self._layout)
@@ -648,7 +665,8 @@ class ServeGroup:
                 tracer.span("replica_join", "group", t_join0,
                             time.monotonic(), rank=ctx.rank, epoch=epoch,
                             reason=reason, complete=True)
-            report = RankReport(rank=ctx.rank, metrics=replica.metrics)
+            report = RankReport(rank=ctx.rank, metrics=replica.metrics,
+                                device=str(replica.device))
             report.events.append(("join", epoch, reason))
             return serve_rounds(ctx, comm, replica, tracer, report, epoch,
                                 inject_faults=False)
@@ -670,7 +688,8 @@ class ServeGroup:
                         epoch=epoch0, outstanding=len(ledger.replayed),
                         answered=len(replay_info.responses))
                 replica = build_replica(ctx.rank, tracer)
-                report = RankReport(rank=ctx.rank, metrics=replica.metrics)
+                report = RankReport(rank=ctx.rank, metrics=replica.metrics,
+                                    device=str(replica.device))
                 return serve_rounds(ctx, comm, replica, tracer, report,
                                     epoch0)
             # dormant spare: pre-warm at spawn (replica build + jit warmup,

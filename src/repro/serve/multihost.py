@@ -66,6 +66,7 @@ DESIGN.md §3.9 for the host fault-domain contract.
 """
 from __future__ import annotations
 
+import glob
 import json
 import math
 import os
@@ -103,6 +104,20 @@ SUPERVISOR_PID = 1 << 10
 HOST_FAULT_KINDS = frozenset({"host_kill", "host_stop"})
 
 _SIM_VOCAB = 512
+
+
+def host_tpu_chips() -> int:
+    """TPU chips this host's worker processes would use, counted from the
+    host's device files: the supervisor never touches JAX itself, because
+    the first process to start the TPU backend holds the chips. 0 where
+    ``JAX_PLATFORMS`` keeps JAX off the TPU (the workers then run on the
+    CPU) or the host has none."""
+    platforms = [p for p in os.environ.get("JAX_PLATFORMS", "").split(",")
+                 if p]
+    if platforms and "tpu" not in platforms:
+        return 0
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
 
 
 # ------------------------------------------------------------------- framing
@@ -379,6 +394,12 @@ class MultiHostSupervisor:
         if backend not in ("sim", "replica"):
             raise ValueError(f"unknown worker backend {backend!r} "
                              "(known: sim, replica)")
+        self.chips = host_tpu_chips() if backend == "replica" else 0
+        if self.chips and nranks > self.chips:
+            raise ValueError(
+                f"{nranks} replica workers on a host with {self.chips} TPU "
+                "chip(s): each worker needs a chip of its own (one process "
+                "per chip), and a worker without one fails or hangs at start")
         self.nranks = int(nranks)
         self.backend = backend
         self.arch = arch
@@ -418,15 +439,28 @@ class MultiHostSupervisor:
             "jax_coordinator": self.jax_coordinator,
         }
 
-    def _spawn(self, rank: int, port: int) -> subprocess.Popen:
+    def _worker_env(self, rank: int) -> dict:
+        """Worker ``rank``'s environment: ``src`` importable; sim workers
+        never touch an accelerator, and on a TPU host each replica worker
+        sees one chip of its own (a chip belongs to one process at a time)."""
         env = dict(os.environ)
         src = os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
         env["PYTHONPATH"] = (src + os.pathsep + env["PYTHONPATH"]
                              if env.get("PYTHONPATH") else src)
+        if self.backend == "sim":
+            env["JAX_PLATFORMS"] = "cpu"
+        elif self.chips:
+            env.update(TPU_VISIBLE_CHIPS=str(rank),
+                       TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                       TPU_PROCESS_BOUNDS="1,1,1")
+        return env
+
+    def _spawn(self, rank: int, port: int) -> subprocess.Popen:
         cmd = self.worker_cmd + [
             "--spec", json.dumps(self._worker_spec(rank, port))]
-        return subprocess.Popen(cmd, env=env, stdout=subprocess.DEVNULL)
+        return subprocess.Popen(cmd, env=self._worker_env(rank),
+                                stdout=subprocess.DEVNULL)
 
     # ------------------------------------------------------------------ serve
     def serve(self, requests: Sequence[Request], *,
@@ -570,8 +604,12 @@ class MultiHostSupervisor:
 
         def fire_faults(now: float) -> None:
             while pending_faults and retired_total >= pending_faults[0].step:
+                rank = int(pending_faults[0].rank)
+                if rank in live and rank not in ready:
+                    # not started yet: its lease (and the detector's watch)
+                    # begins at hello, which fires this again
+                    return
                 spec = pending_faults.pop(0)
-                rank = int(spec.rank)
                 proc = procs.get(rank)
                 if rank not in live or rank in done or proc is None \
                         or proc.poll() is not None:
@@ -872,19 +910,20 @@ def worker_main(argv: Optional[Sequence[str]] = None) -> int:
     rank = int(spec["rank"])
     io_timeout = float(spec.get("io_timeout", 120.0))
 
-    # cross-host runtime, gated: localhost CPU workers run standalone
+    # cross-host runtime, gated: localhost CPU workers run standalone. A
+    # failed initialize raises — a worker must not serve outside its mesh.
     coord = spec.get("jax_coordinator")
     if coord:
-        try:
-            import jax
-            jax.distributed.initialize(coordinator_address=coord,
-                                       num_processes=int(spec["nranks"]),
-                                       process_id=rank)
-        except Exception:
-            pass
+        import jax
+        jax.distributed.initialize(coordinator_address=coord,
+                                   num_processes=int(spec["nranks"]),
+                                   process_id=rank)
 
     tracer = Tracer(pid=rank) if spec.get("trace") else NULL_TRACER
     if spec.get("backend") == "replica":
+        from ..launch.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
         backend = _ReplicaBackend(spec, tracer)
     else:
         sim = spec.get("sim") or {}

@@ -94,6 +94,13 @@ from .scheduler import ContinuousBatchingScheduler, PageAllocator, PagePoolExhau
 SERVE_PROBES = ProbeConfig(use_kernel=False)
 
 
+def _home_device(tree):
+    """The one device every leaf of ``tree`` lives on, or None."""
+    devices = {d for leaf in jax.tree_util.tree_leaves(tree)
+               for d in leaf.devices()}
+    return devices.pop() if len(devices) == 1 else None
+
+
 @functools.lru_cache(maxsize=None)
 def make_enum_fn(num_slots: int):
     """Jitted ``(words, mask) -> (combined, count, table)`` over the slot axis.
@@ -283,7 +290,12 @@ class Replica:
         # a (slots, max_pages) table; the allocator owns the free list and
         # the per-slot ownership ledger (DESIGN.md §3.3)
         self.paged = bool(paged)
+        # a single-device replica lives where its params live: its caches go
+        # to that device too (a ServeGroup puts each rank on its own chip)
+        self.device = _home_device(self.params) if config.tp == 1 else None
         one = self.model.init_cache(1, max_len)
+        if self.device is not None:
+            one = jax.device_put(one, self.device)
         if self.paged:
             if not self.window:
                 raise ValueError("paged=True requires window mode (window=K)")
@@ -342,6 +354,8 @@ class Replica:
                 lambda v: jnp.broadcast_to(v[None],
                                            (num_slots, *v.shape)).copy(),
                 one)
+        if self.device is not None:
+            self.caches = jax.device_put(self.caches, self.device)
         # ---- tensor parallelism (tp > 1, window + overlap mode) -----------
         # one replica = tp shards of a "model" mesh: params and cache leaves
         # are STORED sharded (rules.param_specs / tp_storage_specs), compute

@@ -140,9 +140,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import enumerate_errors_ref, make_enumerate_fn
-kw = ({"axis_types": (jax.sharding.AxisType.Auto,)}
-      if hasattr(jax.sharding, "AxisType") else {})
-mesh = jax.make_mesh((8,), ("ranks",), **kw)
+mesh = jax.make_mesh((8,), ("ranks",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 run = make_enumerate_fn(mesh, "ranks")
 rng = np.random.default_rng(0)
 for trial in range(20):

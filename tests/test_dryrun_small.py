@@ -26,8 +26,6 @@ with mesh:
     lowered = jax.jit(step, in_shardings=shardings).lower(*args)
     compiled = lowered.compile()
 cost = compiled.cost_analysis()
-if isinstance(cost, (list, tuple)):   # jax 0.4.x: one dict per program
-    cost = cost[0] if cost else {}
 mem = compiled.memory_analysis()
 hlo = compiled.as_text()
 coll = parse_collectives(hlo)

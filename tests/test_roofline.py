@@ -5,11 +5,9 @@ import pytest
 
 from repro.configs import SHAPES, get_config
 from repro.roofline.analysis import (
-    HBM_BW,
-    ICI_LINK_BW,
-    PEAK_FLOPS_BF16,
     RooflineTerms,
     model_flops_for,
+    peaks_for,
 )
 from repro.roofline.hlo import (
     _shape_bytes,
@@ -69,6 +67,7 @@ def test_roofline_terms_math():
     t = RooflineTerms(chips=256, hlo_flops_per_device=197e12,
                       hlo_bytes_per_device=819e9,
                       collective_bytes_per_device=50e9,
+                      peaks=peaks_for("TPU v5 lite"),
                       model_flops=197e12 * 256)
     assert t.compute_s == pytest.approx(1.0)
     assert t.memory_s == pytest.approx(1.0)
@@ -84,3 +83,9 @@ def test_model_flops_moe_uses_active():
     assert train == pytest.approx(expect)
     dec = model_flops_for(cfg, SHAPES["decode_32k"])
     assert dec == pytest.approx(2.0 * cfg.active_params_count() * 128)
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", ""])
+def test_peaks_unknown_device_kind_raises(kind):
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks_for(kind)

@@ -90,6 +90,27 @@ def test_supervisor_validates_eagerly():
         MultiHostSupervisor(3, evict_factor=3.0)
 
 
+def test_one_replica_worker_per_chip(monkeypatch):
+    """Replica workers each get a chip of their own; more workers than
+    chips is refused up front instead of hanging at start-up."""
+    from repro.serve import multihost
+
+    monkeypatch.setattr(multihost, "host_tpu_chips", lambda: 2)
+    with pytest.raises(ValueError, match="one process per chip"):
+        MultiHostSupervisor(3, backend="replica")
+    sup = MultiHostSupervisor(2, backend="replica")
+    assert [sup._worker_env(r)["TPU_VISIBLE_CHIPS"] for r in (0, 1)] == \
+        ["0", "1"]
+
+
+def test_sim_workers_stay_off_accelerators(monkeypatch):
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    assert sim_supervisor()._worker_env(0)["JAX_PLATFORMS"] == "cpu"
+    from repro.serve.multihost import host_tpu_chips
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert host_tpu_chips() == 0
+
+
 def test_rejects_device_fault_kinds():
     sup = sim_supervisor()
     with pytest.raises(ValueError, match="host faults"):

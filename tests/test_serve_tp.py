@@ -199,6 +199,22 @@ def test_shard_loss_shrinks_group_with_zero_drops(env):
     assert postmortem.validate(trace) == []
 
 
+def test_group_ranks_serve_from_their_own_devices(env):
+    """A tp=1 group puts rank r's params and caches on device r: with as many
+    devices as ranks no two ranks share one, and a kill moves the dead
+    rank's requests to a survivor on its own device with zero drops."""
+    cfg, _ = env
+    group = ServeGroup(cfg, TP, config=_config(1, max_len=48))
+    placed = [group.device_of(r) for r in range(TP)]
+    assert len(set(placed)) == TP
+    out = group.serve(_requests(6, max_new=6, prompt_len=4),
+                      faults=FaultSchedule([FaultSpec(step=1, kind="kill",
+                                                      rank=0)]))
+    assert set(out.responses) == set(range(6))
+    assert all(r.status == OK for r in out.responses.values())
+    assert out.report(1).device == str(placed[1])
+
+
 # --------------------------------------------------------------- corpus replay
 _CORPUS = sorted((pathlib.Path(__file__).parent / "fuzz_corpus")
                  .glob("seed_overlap_0_*.json"))
