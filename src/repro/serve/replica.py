@@ -388,6 +388,7 @@ class Replica:
         self._slot_logits = jnp.zeros((num_slots, 1, 1, cfg.vocab_size),
                                       jnp.float32)
         self._step_count = 0
+        self._cycles = 0        # step() calls: the serve.step phase's index
         # ---- zero-sync decode windows (window=K > 0) ----------------------
         if self.window:
             if window_fn is not None:
@@ -784,7 +785,37 @@ class Replica:
     # ------------------------------------------------------------- step cycle
     def step(self) -> list[Response]:
         """One scheduler cycle: expire → backfill/prefill → fused decode →
-        commit. Returns every request answered during the cycle."""
+        commit. Returns every request answered during the cycle.
+
+        Traced, the cycle is the ``serve.step`` phase; the window engine's
+        parts of it are the ``serve.admit`` / ``dispatch`` / ``wait`` /
+        ``commit`` / ``recover`` phases (``repro.obs.trace``)."""
+        self._cycles += 1
+        with self.trace.phase("serve.step") as ph:
+            if self.trace.enabled:
+                ph.note(step=self._cycles)
+            with self.trace.phase("serve.admit") as adm:
+                if self.trace.enabled:
+                    adm.note(step=self._cycles)
+                out = self._admit()
+            self.metrics.record_active_slots(self.sched.in_flight())
+            if self.window:
+                if self.sched.has_active() or self._pending is not None:
+                    out.extend(self._window_cycle())
+            elif self.sched.has_active():
+                out.extend(self._decode_step())
+            for resp in out:
+                self.metrics.record_response(resp)
+            if self.trace.enabled:
+                t_done = self.clock()
+                for resp in out:
+                    self.trace.end_request(resp, t_done)
+                self._sweep_recoveries(t_done)
+        return out
+
+    def _admit(self) -> list[Response]:
+        """Expire what passed its deadline and backfill free slots (a
+        blocking prefill when not overlapped); returns the answers."""
         now = self.clock()
         out: list[Response] = []
         for req in self.queue.drain_expired(now):
@@ -806,19 +837,6 @@ class Replica:
                 resp = self._prefill_slot(slot)
                 if resp is not None:
                     out.append(resp)
-        self.metrics.record_active_slots(self.sched.in_flight())
-        if self.window:
-            if self.sched.has_active() or self._pending is not None:
-                out.extend(self._window_cycle())
-        elif self.sched.has_active():
-            out.extend(self._decode_step())
-        for resp in out:
-            self.metrics.record_response(resp)
-        if self.trace.enabled:
-            t_done = self.clock()
-            for resp in out:
-                self.trace.end_request(resp, t_done)
-            self._sweep_recoveries(t_done)
         return out
 
     def run(self, *, max_steps: int = 100_000) -> list[Response]:
@@ -931,11 +949,18 @@ class Replica:
         """Double-buffered commit loop: dispatch window N+1 from window N's
         device-resident outputs *before* reading back window N's tokens."""
         prev = self._pending
-        self._pending = (self._dispatch_window()
-                         if self.sched.has_active() else None)
+        if self.sched.has_active():
+            # window N (``prev``) stays pending while N+1 is planned: page
+            # pressure may still invalidate its lanes
+            with self.trace.phase("serve.dispatch") as ph:
+                self._pending = self._dispatch_window(ph)
+        else:
+            self._pending = None
         return self._retire_window(prev) if prev is not None else []
 
-    def _dispatch_window(self) -> _WindowInFlight:
+    def _dispatch_window(self, ph) -> _WindowInFlight:
+        """Plan, build and enqueue the next window; ``ph`` is the open
+        ``serve.dispatch`` phase, which gets the window's lane counts."""
         self._step_count += 1
         sched = self.sched
         K = self.window
@@ -1040,6 +1065,11 @@ class Replica:
         combined, count, table, hist = self._wenum(words, jnp.asarray(mask))
         fut = DeviceFuture(outputs=outputs, word=combined, count=count,
                            table=table, history=hist)
+        if self.trace.enabled:
+            ph.note(window=self._step_count, slots=sched.num_slots,
+                    lanes=sched.in_flight(),
+                    prefill_lanes=int(np.count_nonzero(rem0)),
+                    prompt_tokens=int(rem0.sum()))
         return _WindowInFlight(
             fut=fut,
             req_ids=tuple(s.req.id if s.active else None for s in sched.slots),
@@ -1054,28 +1084,34 @@ class Replica:
                        if self.trace.enabled else ()))
 
     def _retire_window(self, win: _WindowInFlight) -> list[Response]:
-        if not win.fut.done():
-            # the device is still computing this window at its retirement —
-            # the pipeline, not the host, is the bottleneck right now
-            self.metrics.record_window_wait()
+        with self.trace.phase("serve.wait") as ph:
+            ready = win.fut.done()
+            if not ready:
+                # the device is still computing this window at its retirement
+                # — the pipeline, not the host, is the bottleneck right now
+                self.metrics.record_window_wait()
             if self.trace.enabled:
-                self.trace.instant("window_wait", "window", window=win.index)
-        try:
-            block = win.fut.wait()
-        except PropagatedError as exc:
+                ph.note(window=win.index, ready=ready)
+            exc = None
+            try:
+                win.fut.wait()
+            except PropagatedError as e:
+                exc = e
             if self.trace.enabled:
                 self.trace.span("window", "window", win.t_dispatch,
-                                self.clock(), window=win.index, faulted=True)
-            return self._recover_window(win, exc)
-        if self.trace.enabled:
-            self.trace.span("window", "window", win.t_dispatch, self.clock(),
-                            window=win.index, faulted=False)
+                                self.clock(), window=win.index,
+                                faulted=exc is not None)
+            # a faulted window's tokens are read too: its clean prefix commits
+            block = jax.device_get(win.fut.outputs)
         if self.speculate:
-            toks, counts = (np.asarray(x) for x in jax.device_get(block))
+            toks, counts = (np.asarray(x) for x in block)
+        else:
+            toks, counts = np.asarray(block), None
+        if exc is not None:
+            return self._recover_window(win, exc, toks, counts)
+        if self.speculate:
             self._note_advance(win, counts)
-            return self._commit_window(win, toks, counts=counts)
-        toks = np.asarray(jax.device_get(block))
-        return self._commit_window(win, toks)
+        return self._commit_window(win, toks, counts=counts)
 
     def _note_advance(self, win: _WindowInFlight, counts: np.ndarray,
                       metric_limits: Optional[np.ndarray] = None) -> None:
@@ -1144,59 +1180,89 @@ class Replica:
         cleared) are skipped. With speculation (``counts`` given) a window
         step contributes its variable accepted prefix instead of one token —
         the variable-commit contract of DESIGN.md §3.4."""
-        now = self.clock()
-        K = self.window
-        out: list[Response] = []
-        committed = discarded = 0
-        for slot, rid in enumerate(win.req_ids):
-            if rid is None:
-                continue                         # lane was free at dispatch
-            lo = int(win.start[slot])            # prompt-feed steps emit no
-            s = self.sched.slots[slot]           # committable tokens
-            if counts is None:
-                emitted = K - lo
-            else:
-                # the flip step's leading prompt rows are fed, not generated
-                emitted = max(int(counts[lo:, slot].sum())
-                              - int(win.start_row[slot]), 0)
-            if not s.active or s.req.id != rid or not win.valid[slot]:
-                discarded += emitted
-                continue
-            limit = K if limits is None else int(limits[slot])
-            if limit <= lo:
-                block = []
-            elif counts is None:
-                block = toks[lo:limit, slot]
-            else:
-                block = self._flat_block(win, toks, counts, slot, lo, limit)
+        with self.trace.phase("serve.commit") as ph:
+            now = self.clock()
+            K = self.window
+            out: list[Response] = []
+            committed = discarded = 0
+            for slot, rid in enumerate(win.req_ids):
+                if rid is None:
+                    continue                     # lane was free at dispatch
+                lo = int(win.start[slot])        # prompt-feed steps emit no
+                s = self.sched.slots[slot]       # committable tokens
+                if counts is None:
+                    emitted = K - lo
+                else:
+                    # the flip step's leading prompt rows are fed, not
+                    # generated
+                    emitted = max(int(counts[lo:, slot].sum())
+                                  - int(win.start_row[slot]), 0)
+                if not s.active or s.req.id != rid or not win.valid[slot]:
+                    discarded += emitted
+                    continue
+                limit = K if limits is None else int(limits[slot])
+                if limit <= lo:
+                    block = []
+                elif counts is None:
+                    block = toks[lo:limit, slot]
+                else:
+                    block = self._flat_block(win, toks, counts, slot, lo,
+                                             limit)
+                if self.trace.enabled:
+                    # capture before commit: a finishing lane clears its slot
+                    tr = s.req.trace_id
+                    first_before = s.t_first
+                k, done = (self.sched.commit_block(slot, block, now)
+                           if len(block) else (0, None))
+                committed += k
+                discarded += emitted - k
+                if self.trace.enabled and tr is not None:
+                    self.trace.span("decode", "window", win.t_dispatch, now,
+                                    tid=slot, trace_id=tr, window=win.index,
+                                    committed=k, discarded=emitted - k)
+                    if k and first_before is None:
+                        self.trace.instant("first_token", "request", ts=now,
+                                           tid=slot, trace_id=tr)
+                    if k:
+                        self._trace_recovery_end(slot, tr, now, "recovered")
+                if done is not None:
+                    out.append(done)
+            self.metrics.record_window(committed, discarded, K)
             if self.trace.enabled:
-                # capture before commit: a finishing lane clears its slot
-                tr = s.req.trace_id
-                first_before = s.t_first
-            k, done = (self.sched.commit_block(slot, block, now)
-                       if len(block) else (0, None))
-            committed += k
-            discarded += emitted - k
-            if self.trace.enabled and tr is not None:
-                self.trace.span("decode", "window", win.t_dispatch, now,
-                                tid=slot, trace_id=tr, window=win.index,
-                                committed=k, discarded=emitted - k)
-                if k and first_before is None:
-                    self.trace.instant("first_token", "request", ts=now,
-                                       tid=slot, trace_id=tr)
-                if k:
-                    self._trace_recovery_end(slot, tr, now, "recovered")
-            if done is not None:
-                out.append(done)
-        self.metrics.record_window(committed, discarded, K)
+                ph.note(window=win.index, committed=committed,
+                        discarded=discarded)
         return out
 
-    def _recover_window(self, win: _WindowInFlight,
-                        exc: PropagatedError) -> list[Response]:
+    def _recover_window(self, win: _WindowInFlight, exc: PropagatedError,
+                        toks: np.ndarray,
+                        counts: Optional[np.ndarray]) -> list[Response]:
         """Deferred-detection recovery: the ``(K, slots)`` history attributes
         the fault to its exact ``(step, slot)``; the clean prefix before the
         fault step commits (it is part of the deterministic greedy trajectory)
-        and only the faulted suffix is recomputed via LFLR re-prefill."""
+        and only the faulted suffix is recomputed via LFLR re-prefill.
+
+        Traced, the attribution and the LFLR lanes are two ``serve.recover``
+        phases on either side of the prefix's ``serve.commit``."""
+        with self.trace.phase("serve.recover") as ph:
+            if self.trace.enabled:
+                ph.note(window=win.index)
+            plan = self._attribute_fault(win, exc, counts)
+        if plan is None:
+            return self._commit_window(win, toks, counts=counts)
+        limits, *lanes = plan
+        out = self._commit_window(win, toks, limits=limits, counts=counts)
+        with self.trace.phase("serve.recover") as ph:
+            if self.trace.enabled:
+                ph.note(window=win.index)
+            out.extend(self._restart_lanes(win, *lanes))
+        return out
+
+    def _attribute_fault(self, win: _WindowInFlight, exc: PropagatedError,
+                         counts: Optional[np.ndarray]) -> Optional[tuple]:
+        """Attribute a retired window's fault and decide the recovery:
+        ``(limits, faulted, decision, codes)``, with ``limits`` each lane's
+        committable steps, or None when every attributed lane was already
+        patched (a stale fault: the whole window commits)."""
         num_slots = self.sched.num_slots
         K = self.window
         faulted = sorted({e.rank for e in exc.errors if 0 <= e.rank < num_slots})
@@ -1206,16 +1272,10 @@ class Replica:
         # fault (the window *computed* with the poisoned state even though the
         # state has since been repaired) — stale, already recovered: drop it
         faulted = [s for s in faulted if win.valid[s]]
-        if self.speculate:
-            toks, counts = (np.asarray(x)
-                            for x in jax.device_get(win.fut.outputs))
-        else:
-            toks = np.asarray(jax.device_get(win.fut.outputs))
-            counts = None
         if not faulted:
             if self.speculate:
                 self._note_advance(win, counts)
-            return self._commit_window(win, toks, counts=counts)
+            return None
         # first *faulting* step per slot: attribution-only lanes (speculation
         # misses) are masked out, so a rejected draft never truncates the
         # clean committable prefix — and a real fault mid-speculation drops
@@ -1276,13 +1336,20 @@ class Replica:
                         "shard_fanout", "shard", ts=t_fault,
                         tid=SHARD_TID + shard, shard=shard, tp=self.tp,
                         window=win.index, code=int(exc.combined_code))
+        return limits, faulted, decision, codes
+
+    def _restart_lanes(self, win: _WindowInFlight, faulted: list,
+                       decision, codes) -> list[Response]:
+        """After the clean prefix committed: fail the lanes past their retry
+        budget and send the rest through LFLR (every active lane on a
+        ROLLBACK)."""
         if decision.action is Action.ROLLBACK:
             targets, fail_now = list(self.sched.active_slots()), False
         elif decision.action is Action.ABORT:
             targets, fail_now = faulted, True
         else:   # SKIP_BATCH / RESTORE_GOOD / CONTINUE / ... → per-sequence LFLR
             targets, fail_now = faulted, False
-        out = self._commit_window(win, toks, limits=limits, counts=counts)
+        out: list[Response] = []
         faulted_set = set(faulted)
         for slot in targets:
             s = self.sched.slots[slot]

@@ -306,18 +306,143 @@ def test_group_kill_shrink_reroute_one_connected_trace():
 # ----------------------------------------------- no-op tracer / sampling
 def test_null_tracer_bit_exact_and_recordless(env):
     """The default (no tracer) serve path records zero events and emits the
-    bit-identical token stream a traced replica does."""
+    bit-identical token stream a traced replica does; an explicit
+    NullTracer serves the same tokens. The null tracer's phase is one shared
+    no-op context, and the traced run records every engine phase."""
     plain = _replica(env, None)
     assert isinstance(plain.trace, NullTracer) and not plain.trace.enabled
     base = _serve(plain, _requests(3), inject_at=3)
     assert plain.trace.num_events == 0
     assert NULL_TRACER.num_events == 0
+    null = NullTracer()
+    off = _serve(_replica(env, null), _requests(3), inject_at=3)
+    assert null.num_events == 0
+    ph = NULL_TRACER.phase("serve.step", window=1)
+    assert ph is NULL_TRACER.phase("serve.wait") is null.phase("serve.commit")
+    with ph as p:
+        p.note(window=2)
+    assert NULL_TRACER.num_events == 0 and null.num_events == 0
     tr = Tracer()
     got = _serve(_replica(env, tr), _requests(3), inject_at=3)
-    assert sorted(got) == sorted(base)
+    assert sorted(got) == sorted(base) == sorted(off)
     for i in base:
-        assert got[i].tokens == base[i].tokens, i
+        assert got[i].tokens == base[i].tokens == off[i].tokens, i
     assert tr.num_events > 0
+    assert {e["name"] for e in tr.events() if e["cat"] == "phase"} == set(
+        PHASES)
+
+
+# ----------------------------------------------------- engine phases
+PHASES = ("serve.step", "serve.admit", "serve.dispatch", "serve.wait",
+          "serve.commit", "serve.recover")
+
+
+def test_phase_records_one_span_with_its_arguments():
+    """``Tracer.phase`` records one ``X`` event on the tracer's clock, on the
+    engine lane, with the arguments given at open and those noted inside."""
+    tr = Tracer(pid=3, clock=_clock([2.0, 2.5]))
+    with tr.phase("serve.dispatch", window=7) as ph:
+        ph.note(lanes=5, slots=8)
+    (ev,) = tr.events()
+    assert ev["name"] == "serve.dispatch" and ev["cat"] == "phase"
+    assert ev["ph"] == "X" and ev["tid"] == ENGINE_TID and ev["pid"] == 3
+    assert ev["ts"] == pytest.approx(2.0e6)
+    assert ev["dur"] == pytest.approx(0.5e6)
+    assert _args(ev) == {"window": 7, "lanes": 5, "slots": 8}
+
+
+def _phases(events):
+    return sorted((e for e in events if e.get("cat") == "phase"),
+                  key=lambda e: e["ts"])
+
+
+@pytest.mark.parametrize("inject_at", [None, 3], ids=["clean", "faulted"])
+def test_phase_counters_sum_to_the_run_counters(env, inject_at):
+    """Each step's phases nest inside its ``serve.step`` without
+    overlapping; the dispatch counters (``prompt_tokens``, ``lanes`` of
+    ``slots``) and commit counters (``committed``, ``discarded``) sum to
+    what ``ServeMetrics`` counted over the run."""
+    tr = Tracer()
+    rep = _replica(env, tr, num_slots=3)
+    reqs = [Request(id=i, prompt=tuple(3 + i + j for j in range(5 + 3 * i)),
+                    max_new_tokens=12 + 4 * i) for i in range(5)]
+    out = _serve(rep, reqs, inject_at=inject_at)
+    assert all(r.status == OK for r in out.values())
+    evs = _phases(tr.events())
+    steps = [e for e in evs if e["name"] == "serve.step"]
+    assert [_args(e)["step"] for e in steps] == list(
+        range(steps[0]["args"]["step"], steps[0]["args"]["step"] + len(steps)))
+    for st in steps:
+        end = st["ts"] + st["dur"]
+        kids = [e for e in evs if e["name"] != "serve.step"
+                and st["ts"] <= e["ts"] < end]
+        assert kids and kids[0]["name"] == "serve.admit"
+        t = st["ts"]
+        for k in kids:
+            assert k["ts"] >= t and k["ts"] + k["dur"] <= end
+            t = k["ts"] + k["dur"]
+    disp = [_args(e) for e in evs if e["name"] == "serve.dispatch"]
+    com = [_args(e) for e in evs if e["name"] == "serve.commit"]
+    m = rep.metrics
+    assert sum(d["prompt_tokens"] for d in disp) == m.prefill_chunk_tokens
+    assert sum(d["prefill_lanes"] for d in disp) == m.prefill_chunks
+    assert all(d["prefill_lanes"] <= d["lanes"] <= d["slots"] == 3
+               for d in disp)
+    assert sum(c["committed"] for c in com) == m.decode_tokens
+    assert sum(c["discarded"] for c in com) == m.discarded_tokens
+    assert len(com) == m.windows
+    assert sorted(c["window"] for c in com) == sorted(
+        d["window"] for d in disp)
+    waits = [_args(e) for e in evs if e["name"] == "serve.wait"]
+    assert len(waits) == m.windows
+    assert sum(not w["ready"] for w in waits) == m.window_waits
+    assert not _by_name(tr.events(), "window_wait")
+    recover = [e for e in evs if e["name"] == "serve.recover"]
+    assert bool(recover) == (inject_at is not None)
+
+
+def test_phases_on_the_profiler_clock(env, tmp_path):
+    """Under the JAX profiler every ``serve.*`` span of the tracer has an
+    annotation of the same name on the host plane, with the same duration,
+    and one offset maps the tracer's clock onto the trace's."""
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    rep = _replica(env, tr, num_slots=2)
+    rep.warmup(max_new=8)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = _serve(rep, _requests(3, max_new=12), inject_at=2)
+    finally:
+        jax.profiler.stop_trace()
+    assert all(r.status == OK for r in out.values())
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    ann = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("serve."):
+                    ann.setdefault(e.name, []).append(
+                        (e.start_ns * 1e-3, e.duration_ns * 1e-3,
+                         dict(e.stats)))
+    spans = {}
+    for e in _phases(tr.events()):
+        spans.setdefault(e["name"], []).append(e)
+    assert set(spans) == set(PHASES) == set(ann)
+    offsets = []
+    for name, evs in spans.items():
+        got = sorted(ann[name], key=lambda a: a[0])
+        assert len(got) == len(evs), name
+        for e, (start, dur, stats) in zip(evs, got):
+            assert abs(dur - e["dur"]) <= 200.0, name      # µs
+            for key in ("window", "step"):
+                if key in _args(e):
+                    assert stats[key] == _args(e)[key], name
+            offsets.append(start - e["ts"])
+    mid = sorted(offsets)[len(offsets) // 2]
+    assert max(abs(o - mid) for o in offsets) <= 200.0
 
 
 def test_sampling_is_deterministic_and_engine_spans_survive(env):
@@ -328,6 +453,7 @@ def test_sampling_is_deterministic_and_engine_spans_survive(env):
     assert all(r.status == OK for r in out.values())
     evs = tr.events()
     assert _by_name(evs, "window")          # engine spans always kept
+    assert _by_name(evs, "serve.dispatch")
     assert not _by_name(evs, "submit")
     assert all(_args(e).get("trace_id") is None for e in evs)
     assert all(r.trace_id is None for r in out.values())
