@@ -349,6 +349,33 @@ def attention_train(p, x, positions, cfg, *, window: int = 0,
 
 
 # ----------------------------------------------------------------- decode paths
+#
+# Decode attention contracts each GQA group of query heads against its KV head
+# in the cache as stored: q (B,S,H,D) is viewed as (B,S,Kv,g,D), so no
+# ``repeat``-ed (…,T,H,D) copy and no float32 copy of the cache is made. A bf16
+# product is exact in float32, so the scores equal the upcast form's; scores,
+# softmax, probs and both accumulations stay float32.
+def _gqa_scores(q, k):
+    """q (B,S,H,D) · k (B,T,Kv,D) -> float32 scores (B,Kv,g,S,T)."""
+    B, S, H, D = q.shape
+    Kv = k.shape[2]
+    qg = q.reshape(B, S, Kv, H // Kv, D)
+    return jnp.einsum("bskgd,btkd->bkgst", qg, k,
+                      preferred_element_type=jnp.float32)
+
+
+def _gqa_values(probs, v):
+    """float32 probs (B,Kv,g,S,T) · v (B,T,Kv,D) -> float32 (B,S,H,D).
+
+    ``HIGHEST`` keeps the probs float32: at the default precision the TPU
+    rounds a float32 operand to bf16."""
+    B, Kv, g, S, _ = probs.shape
+    out = jnp.einsum("bkgst,btkd->bskgd", probs, v,
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, S, Kv * g, v.shape[-1])
+
+
 def init_kv_cache(batch: int, length: int, n_kv: int, head_dim: int, dtype):
     return {
         "k": jnp.zeros((batch, length, n_kv, head_dim), dtype),
@@ -391,14 +418,10 @@ def attention_decode(p, x, cache, pos, cfg, *, window: int = 0,
         slot_pos = slots
         valid = slots <= pos
 
-    group = cfg.num_heads // cfg.num_kv_heads
-    kr = jnp.repeat(k, group, axis=2)
-    vr = jnp.repeat(v, group, axis=2)
-    scores = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32),
-                        kr.astype(jnp.float32)) / jnp.sqrt(float(hd))
-    scores = jnp.where(valid[None, None, None, :], scores, NEG_INF)
+    scores = _gqa_scores(q, k) / jnp.sqrt(float(hd))
+    scores = jnp.where(valid[None, None, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhst,bthd->bshd", probs, vr.astype(jnp.float32))
+    out = _gqa_values(probs, v)
     out = jnp.einsum("bshe,hed->bsd", out.astype(x.dtype), p["wo"])
     return out, {"k": k, "v": v}
 
@@ -436,12 +459,8 @@ def attention_verify(p, x, cache, pos, cfg):
     slots = jnp.arange(cap)
     valid = slots[None, :] <= qpos[:, None]          # (T, cap) per-query mask
 
-    group = cfg.num_heads // cfg.num_kv_heads
-    kr = jnp.repeat(k, group, axis=2)
-    vr = jnp.repeat(v, group, axis=2)
-    scores = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32),
-                        kr.astype(jnp.float32)) / jnp.sqrt(float(hd))
-    scores = jnp.where(valid[None, None, :, :], scores, NEG_INF)
+    scores = _gqa_scores(q, k) / jnp.sqrt(float(hd))
+    scores = jnp.where(valid[None, None, None, :, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     # the probs·V contraction and the output projection are computed per
     # query row: XLA-CPU's tiling (hence accumulation order) for these two
@@ -454,10 +473,9 @@ def attention_verify(p, x, cache, pos, cfg):
     # any two programs stops being guaranteeable; emitted tokens remain
     # full-model argmaxes (a self-consistent greedy stream), they may just
     # differ from the single-token engine near exact logit ties.
-    vrf = vr.astype(jnp.float32)
     rows = []
     for t in range(T):
-        o_t = jnp.einsum("bhst,bthd->bshd", probs[:, :, t:t + 1, :], vrf)
+        o_t = _gqa_values(probs[:, :, :, t:t + 1, :], v)
         rows.append(jnp.einsum("bshe,hed->bsd", o_t.astype(x.dtype),
                                p["wo"]))
     out = jnp.concatenate(rows, axis=1)

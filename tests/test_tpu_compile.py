@@ -1,5 +1,5 @@
-"""Compile the main path's kernels and the qwen3-1.7b serving window for a
-described TPU v5e chip, at real widths.
+"""Compile the main path's kernels and the serving windows of the benchmark's
+configurations for a described TPU v5e chip, at real widths.
 
 Nothing runs: the TPU compiler (installed with JAX) compiles for a ``v5e:2x2``
 topology that is described, not attached, and refuses what the chip would
@@ -10,7 +10,9 @@ The topology is described inside a fixture, never at import: only one process
 at a time may load the TPU library, and every test worker imports this file.
 All such compiles live in this one file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -104,29 +106,61 @@ def test_rglru_scan_compiles_at_recurrentgemma_widths(one_chip):
     assert _is_kernel(compiled)
 
 
-def test_qwen3_decode_window_compiles_and_fits_one_chip(one_chip):
-    """The serving hot path chip_smoke.py runs: the overlapped K=8 window over
-    8 slots of 2048 positions, params and caches in bf16, caches donated."""
+def _compile_window(sharding, name, slots, max_len=2048, window=8):
+    """The overlapped K-step window over ``slots`` lanes of ``max_len``
+    positions, params and caches in bf16, caches donated; returns the
+    config, the compiled program and the caches' bytes."""
     from repro.configs import get_config
     from repro.launch.steps import make_prefill_decode_window
     from repro.models import build_model
     from repro.serve.replica import SERVE_PROBES
-    cfg = get_config("qwen3-1.7b")
+    cfg = get_config(name)
     model = build_model(cfg)
-    slots, max_len, window = 8, 2048, 8
     put = lambda t: jax.tree_util.tree_map(  # noqa: E731
-        lambda x: _sds(one_chip, x.shape, x.dtype), t)
+        lambda x: _sds(sharding, x.shape, x.dtype), t)
     params = put(model.param_shapes())
     caches = jax.tree_util.tree_map(
-        lambda x: _sds(one_chip, (slots, *x.shape), x.dtype),
+        lambda x: _sds(sharding, (slots, *x.shape), x.dtype),
         model.cache_shapes(1, max_len))
-    i32 = lambda *shape: _sds(one_chip, shape, jnp.int32)  # noqa: E731
+    i32 = lambda *shape: _sds(sharding, shape, jnp.int32)  # noqa: E731
     fn = make_prefill_decode_window(cfg, SERVE_PROBES, window=window,
                                     donate=True)
     compiled = fn.lower(params, caches, i32(slots, 1, 1), i32(slots),
                         i32(window, slots), i32(slots)).compile()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(caches))
+    return cfg, compiled, cache_bytes
+
+
+def test_qwen3_decode_window_compiles_and_fits_one_chip(one_chip):
+    """The serving hot path chip_smoke.py runs: the overlapped K=8 window over
+    8 slots of 2048 positions, params and caches in bf16, caches donated."""
+    _, compiled, _ = _compile_window(one_chip, "qwen3-1.7b", slots=8)
     mem = compiled.memory_analysis()
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert mem.alias_size_in_bytes > 0, "caches were not donated"
     assert live < HBM_BYTES, f"window needs {live / 1e9:.1f} GB"
+
+
+@pytest.mark.parametrize("name", ["qwen3-1.7b", "starcoder2-3b"])
+def test_decode_window_reads_bf16_cache_in_place(one_chip, name):
+    """At the benchmark cells' shapes (16 slots, 2048 positions, K=8) decode
+    attention contracts GQA groups against the bf16 cache as stored: no
+    float32 tensor holds a slot's cache repeated to every query head
+    (positions x num_heads x head_dim) or upcast (positions x num_kv_heads x
+    head_dim), per layer or for the whole stack, and the caches stay
+    donated."""
+    slots, max_len = 16, 2048
+    cfg, compiled, cache_bytes = _compile_window(one_chip, name, slots,
+                                                 max_len)
+    hd = cfg.resolved_head_dim
+    copies = {slots * n * max_len * heads * hd
+              for heads in (cfg.num_heads, cfg.num_kv_heads)
+              for n in (1, cfg.num_layers)}
+    found = sorted({shape for shape in
+                    re.findall(r"f32\[([0-9,]+)\]", compiled.as_text())
+                    if math.prod(map(int, shape.split(","))) in copies})
+    assert not found, f"float32 copies of the KV cache: {found}"
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes, \
+        "caches were not donated"
