@@ -40,6 +40,11 @@ class ModelConfig:
     num_experts: int = 0
     num_experts_per_tok: int = 0
     expert_capacity_factor: float = 1.25
+    # expert parallelism: the experts of every layer are split into
+    # ``expert_shards`` contiguous blocks and this model holds block
+    # ``expert_shard``; the router still scores all ``num_experts``
+    expert_shards: int = 1
+    expert_shard: int = 0
 
     # --- ssm (mamba2 SSD) ----------------------------------------------------
     ssm_state_dim: int = 0
@@ -77,6 +82,10 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts // self.expert_shards
 
     @property
     def d_inner(self) -> int:
@@ -145,7 +154,7 @@ class ModelConfig:
                 if self.is_moe:
                     gate = 3 if self.mlp_kind in ("swiglu", "geglu") else 2
                     total += (d * self.num_experts  # router
-                              + self.num_experts * gate * d * self.d_ff)
+                              + self.experts_held * gate * d * self.d_ff)
                 elif self.d_ff:
                     gate = 3 if self.mlp_kind in ("swiglu", "geglu") else 2
                     total += gate * d * self.d_ff
@@ -158,7 +167,7 @@ class ModelConfig:
         d = self.d_model
         gate = 3 if self.mlp_kind in ("swiglu", "geglu") else 2
         dense_total = self.params_count() - sum(
-            self.num_experts * gate * d * self.d_ff
+            self.experts_held * gate * d * self.d_ff
             for b in self.pattern_layers if b in ("attn", "sliding", "cross"))
         active_ff = sum(
             self.num_experts_per_tok * gate * d * self.d_ff

@@ -29,10 +29,21 @@ ARCHS: dict[str, ModelConfig] = {
     "hubert-xlarge": hubert_xlarge,
 }
 
+#: one chip's share of a stated deployment of an architecture in ``ARCHS``:
+#: Qwen3-30B-A3B with each layer's 128 experts over 16 chips (16-way expert
+#: parallelism, attention data-parallel), this chip holding experts 0-7
+DEPLOYMENTS: dict[str, ModelConfig] = {
+    "qwen3-moe-30b-a3b-ep16": qwen3_moe.replace(
+        name="qwen3-moe-30b-a3b-ep16", expert_shards=16, expert_shard=0),
+}
+
 
 def get_config(name: str) -> ModelConfig:
+    if name in DEPLOYMENTS:
+        return DEPLOYMENTS[name]
     if name not in ARCHS:
-        raise KeyError(f"unknown arch {name!r}; known: {sorted(ARCHS)}")
+        raise KeyError(f"unknown arch {name!r}; known: "
+                       f"{sorted(ARCHS) + sorted(DEPLOYMENTS)}")
     return ARCHS[name]
 
 

@@ -223,12 +223,17 @@ def make_prefill_step(cfg: ModelConfig, probe_cfg: ProbeConfig | None = None, *,
     return prefill_step
 
 
-def make_decode_step(cfg: ModelConfig, probe_cfg: ProbeConfig | None = None):
+def make_decode_step(cfg: ModelConfig, probe_cfg: ProbeConfig | None = None,
+                     *, routed: bool = False):
+    """``(params, cache, token, pos) → (logits, new cache, word)``; with
+    ``routed`` (MoE) a fourth output, the rows routed to each held expert
+    (``Model.decode_step``)."""
     model = build_model(cfg)
     probe_cfg = probe_cfg or ProbeConfig()
 
     def decode_step(params, cache, token, pos):
-        logits, new_cache = model.decode_step(params, token, cache, pos)
+        logits, new_cache, *pairs = model.decode_step(params, token, cache,
+                                                      pos, routed=routed)
         # probe recurrent states only (KV re-probing would double memory traffic)
         words = [loss_probe(jnp.max(jnp.abs(logits)),
                             ProbeConfig(loss_divergence_threshold=jnp.inf))]
@@ -236,12 +241,13 @@ def make_decode_step(cfg: ModelConfig, probe_cfg: ProbeConfig | None = None):
         if rec:
             words.append(state_probe(rec, probe_cfg))
         word = functools.reduce(lambda a, b: a | b, words)
-        return logits, new_cache, word
+        return (logits, new_cache, word, *pairs)
 
     return decode_step
 
 
-def make_slot_decode_step(cfg: ModelConfig, probe_cfg: ProbeConfig | None = None):
+def make_slot_decode_step(cfg: ModelConfig, probe_cfg: ProbeConfig | None = None,
+                          *, routed: bool = False):
     """Per-slot decode for continuous batching (``repro.serve``).
 
     vmap of the single-sequence decode step over a leading *slot* axis, so every
@@ -264,7 +270,7 @@ def make_slot_decode_step(cfg: ModelConfig, probe_cfg: ProbeConfig | None = None
     the serving LFLR recompute (prefill via the scalar decode step) reproduce
     the batched trajectory exactly.
     """
-    return jax.vmap(make_decode_step(cfg, probe_cfg),
+    return jax.vmap(make_decode_step(cfg, probe_cfg, routed=routed),
                     in_axes=(None, 0, 0, 0))
 
 
@@ -282,9 +288,9 @@ def _paged_slot_step(slot_step, paged):
 
     def step(params, hybrid, tokens, pos, table):
         views = paged.gather(hybrid, table)
-        logits, views, words = slot_step(params, views, tokens, pos)
+        logits, views, words, *pairs = slot_step(params, views, tokens, pos)
         hybrid = paged.scatter(hybrid, views, table)
-        return logits, hybrid, words | paged.probe(table, pos)
+        return (logits, hybrid, words | paged.probe(table, pos), *pairs)
 
     return step
 
@@ -412,6 +418,9 @@ def make_decode_window(cfg: ModelConfig, probe_cfg: ProbeConfig | None = None,
         pos     (S,) int32                per-slot absolute position
       → (tokens (K, S) int32,             greedy token emitted per step × slot
          words  (K, S) uint32,            per-(step, slot) error-word history
+         [routed (K, S, H) int32,]        MoE only: rows of each step × slot
+                                          routed to each of the H held
+                                          experts, summed over the layers
          next_tok (S, 1, 1) int32,        device-resident feed for window N+1
          new caches)
 
@@ -434,7 +443,8 @@ def make_decode_window(cfg: ModelConfig, probe_cfg: ProbeConfig | None = None,
     """
     if window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
-    slot_step = make_slot_decode_step(cfg, probe_cfg)
+    routed = cfg.is_moe
+    slot_step = make_slot_decode_step(cfg, probe_cfg, routed=routed)
 
     if paged is not None:
         pstep = _paged_slot_step(slot_step, paged)
@@ -442,36 +452,39 @@ def make_decode_window(cfg: ModelConfig, probe_cfg: ProbeConfig | None = None,
         def paged_window_step(params, hybrid, tokens, pos, table):
             def body(carry, _):
                 hybrid, tok, p = carry
-                logits, hybrid, words = pstep(params, hybrid, tok, p, table)
+                logits, hybrid, words, *pairs = pstep(params, hybrid, tok, p,
+                                                      table)
                 nxt = jnp.argmax(logits[:, 0, 0, :], axis=-1).astype(jnp.int32)
-                return (hybrid, nxt[:, None, None], p + 1), (nxt, words)
+                return (hybrid, nxt[:, None, None], p + 1), (nxt, words,
+                                                             *pairs)
 
-            (hybrid, next_tok, _), (toks, words) = jax.lax.scan(
+            (hybrid, next_tok, _), (toks, words, *pairs) = jax.lax.scan(
                 body, (hybrid, jnp.asarray(tokens, jnp.int32),
                        jnp.asarray(pos, jnp.int32)), None, length=window)
-            return toks, words.astype(jnp.uint32), next_tok, hybrid
+            return (toks, words.astype(jnp.uint32), *pairs, next_tok,
+                    hybrid)
 
         if tp is not None:
             return _tp_window(paged_window_step, tp, n_rest=3,
-                              words_index=1, n_out=4, donate=donate)
+                              words_index=1, n_out=4 + routed, donate=donate)
         return jax.jit(paged_window_step,
                        donate_argnums=(1,) if donate else ())
 
     def window_step(params, caches, tokens, pos):
         def body(carry, _):
             caches, tok, p = carry
-            logits, caches, words = slot_step(params, caches, tok, p)
+            logits, caches, words, *pairs = slot_step(params, caches, tok, p)
             nxt = jnp.argmax(logits[:, 0, 0, :], axis=-1).astype(jnp.int32)
-            return (caches, nxt[:, None, None], p + 1), (nxt, words)
+            return (caches, nxt[:, None, None], p + 1), (nxt, words, *pairs)
 
-        (caches, next_tok, _), (toks, words) = jax.lax.scan(
+        (caches, next_tok, _), (toks, words, *pairs) = jax.lax.scan(
             body, (caches, jnp.asarray(tokens, jnp.int32),
                    jnp.asarray(pos, jnp.int32)), None, length=window)
-        return toks, words.astype(jnp.uint32), next_tok, caches
+        return (toks, words.astype(jnp.uint32), *pairs, next_tok, caches)
 
     if tp is not None:
-        return _tp_window(window_step, tp, n_rest=2, words_index=1, n_out=4,
-                          donate=donate)
+        return _tp_window(window_step, tp, n_rest=2, words_index=1,
+                          n_out=4 + routed, donate=donate)
     return jax.jit(window_step, donate_argnums=(1,) if donate else ())
 
 
@@ -502,7 +515,10 @@ def make_prefill_decode_window(cfg: ModelConfig,
         rem     (S,) int32                prompt-feed steps for each slot:
                                           step k consumes ``chunk[k, s]`` iff
                                           ``k < rem[s]``, else greedy feedback
-      → (tokens (K, S), words (K, S), next_tok (S, 1, 1), new caches)
+      → (tokens (K, S), words (K, S), [routed (K, S, H),] next_tok (S, 1, 1),
+         new caches)
+
+    ``routed`` (MoE only) as in :func:`make_decode_window`.
 
     Flip semantics: when a chunk exhausts a slot's prompt at step ``rem-1``,
     that step's argmax — the logits after the *last* prompt token — is the
@@ -523,7 +539,8 @@ def make_prefill_decode_window(cfg: ModelConfig,
     """
     if window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
-    slot_step = make_slot_decode_step(cfg, probe_cfg)
+    routed = cfg.is_moe
+    slot_step = make_slot_decode_step(cfg, probe_cfg, routed=routed)
 
     if paged is not None:
         pstep = _paged_slot_step(slot_step, paged)
@@ -536,20 +553,23 @@ def make_prefill_decode_window(cfg: ModelConfig,
                 hybrid, tok, p = carry
                 feed = (k < rem)[:, None, None]
                 inp = jnp.where(feed, chunk_row[:, None, None], tok)
-                logits, hybrid, words = pstep(params, hybrid, inp, p, table)
+                logits, hybrid, words, *pairs = pstep(params, hybrid, inp, p,
+                                                      table)
                 nxt = jnp.argmax(logits[:, 0, 0, :], axis=-1).astype(jnp.int32)
-                return (hybrid, nxt[:, None, None], p + 1), (nxt, words)
+                return (hybrid, nxt[:, None, None], p + 1), (nxt, words,
+                                                             *pairs)
 
-            (hybrid, next_tok, _), (toks, words) = jax.lax.scan(
+            (hybrid, next_tok, _), (toks, words, *pairs) = jax.lax.scan(
                 body, (hybrid, jnp.asarray(tokens, jnp.int32),
                        jnp.asarray(pos, jnp.int32)),
                 (jnp.asarray(chunk, jnp.int32),
                  jnp.arange(window, dtype=jnp.int32)))
-            return toks, words.astype(jnp.uint32), next_tok, hybrid
+            return (toks, words.astype(jnp.uint32), *pairs, next_tok,
+                    hybrid)
 
         if tp is not None:
             return _tp_window(paged_window_step, tp, n_rest=5,
-                              words_index=1, n_out=4, donate=donate)
+                              words_index=1, n_out=4 + routed, donate=donate)
         return jax.jit(paged_window_step,
                        donate_argnums=(1,) if donate else ())
 
@@ -561,20 +581,20 @@ def make_prefill_decode_window(cfg: ModelConfig,
             caches, tok, p = carry
             feed = (k < rem)[:, None, None]
             inp = jnp.where(feed, chunk_row[:, None, None], tok)
-            logits, caches, words = slot_step(params, caches, inp, p)
+            logits, caches, words, *pairs = slot_step(params, caches, inp, p)
             nxt = jnp.argmax(logits[:, 0, 0, :], axis=-1).astype(jnp.int32)
-            return (caches, nxt[:, None, None], p + 1), (nxt, words)
+            return (caches, nxt[:, None, None], p + 1), (nxt, words, *pairs)
 
-        (caches, next_tok, _), (toks, words) = jax.lax.scan(
+        (caches, next_tok, _), (toks, words, *pairs) = jax.lax.scan(
             body, (caches, jnp.asarray(tokens, jnp.int32),
                    jnp.asarray(pos, jnp.int32)),
             (jnp.asarray(chunk, jnp.int32),
              jnp.arange(window, dtype=jnp.int32)))
-        return toks, words.astype(jnp.uint32), next_tok, caches
+        return (toks, words.astype(jnp.uint32), *pairs, next_tok, caches)
 
     if tp is not None:
-        return _tp_window(window_step, tp, n_rest=4, words_index=1, n_out=4,
-                          donate=donate)
+        return _tp_window(window_step, tp, n_rest=4, words_index=1,
+                          n_out=4 + routed, donate=donate)
     return jax.jit(window_step, donate_argnums=(1,) if donate else ())
 
 

@@ -125,30 +125,35 @@ class Model:
     def cache_shapes(self, batch: int, max_len: int) -> dict:
         return jax.eval_shape(lambda: self.init_cache(batch, max_len))
 
-    def decode_step(self, params, token, cache, pos):
+    def decode_step(self, params, token, cache, pos, *, routed: bool = False):
         """One new token against an existing cache (serve_step for decode cells).
 
         token: (B, 1) int32; pos: scalar int32 (global position). Returns
-        (fp32 logits (B, 1, V), new cache).
+        (fp32 logits (B, 1, V), new cache), and with ``routed`` a third
+        output: the rows routed to each held expert, summed over the layers
+        ((experts_held,) int32; MoE only).
         """
         cfg = self.cfg
         dt = _dtype(cfg)
         x = embed_tokens(params["embed"], token, dt)
         if cfg.embed_scale != 1.0:
             x = x * jnp.asarray(cfg.embed_scale, dt)
-        x, new_cache, _ = apply_stack_decode(params["stack"], x, cache, pos, cfg)
+        x, new_cache, pairs = apply_stack_decode(params["stack"], x, cache,
+                                                 pos, cfg)
         x = apply_norm(params["final_norm"], x, cfg.norm)
         logits = unembed(params.get("unembed"), params["embed"], x,
                          cfg.tie_embeddings, cfg.logit_softcap)
+        if routed:
+            return logits, new_cache, pairs
         return logits, new_cache
 
     def supports_speculation(self) -> bool:
         """Speculative decode windows need every cache write to be positional
         and idempotent, so a rejected draft's stale entries are overwritten
         before anything reads them: pure full-attention stacks only (ring
-        buffers and recurrent states advance destructively), and no MoE (the
-        router's capacity accounting couples tokens across the verify batch,
-        breaking per-row equality with sequential decode)."""
+        buffers and recurrent states advance destructively), and no MoE (not
+        covered yet: the speculative window returns no routed-pair counters,
+        and no test ties its MoE verify rows to sequential decode)."""
         cfg = self.cfg
         return (all(b == "attn" for b in cfg.pattern_layers)
                 and not cfg.is_moe)

@@ -31,7 +31,7 @@ from .attention import (
     precompute_cross_kv,
 )
 from .layers import apply_mlp, apply_norm, init_mlp, init_norm
-from .moe import apply_moe, init_moe
+from .moe import apply_moe, apply_moe_dropless, init_moe
 from .rglru import init_rglru, init_rglru_cache, rglru_decode, rglru_mixer
 from .ssm import init_mamba2, init_mamba2_cache, mamba2_decode, mamba2_mixer
 
@@ -85,6 +85,21 @@ def _ffn(p, h, cfg):
     if cfg.d_ff:
         return apply_mlp(p["mlp"], h, cfg.mlp_kind), jnp.float32(0)
     return jnp.zeros_like(h), jnp.float32(0)
+
+
+def _ffn_decode(p, h, cfg):
+    """The serving MLP or dropless MoE sub-block; returns (out, routed):
+    the rows routed to each held expert (MoE), else a float zero."""
+    if cfg.is_moe:
+        return apply_moe_dropless(p["moe"], h, cfg)
+    return _ffn(p, h, cfg)
+
+
+def _routed0(cfg):
+    """The zero that the decode stack's routed counts accumulate from."""
+    if cfg.is_moe:
+        return jnp.zeros((cfg.experts_held,), jnp.int32)
+    return jnp.float32(0)
 
 
 def apply_block_train(p, x, positions, cfg, btype: str, *,
@@ -208,7 +223,8 @@ def init_stack_cache(batch, cfg, max_len: int, dtype):
 
 
 def apply_block_decode(p, x, cache, pos, cfg, btype: str):
-    drop = jnp.float32(0)
+    """One-token block against its cache; returns (x, new_cache, routed)."""
+    routed = _routed0(cfg)
     if btype in ATTN_KINDS:
         h = apply_norm(p["norm1"], x, cfg.norm)
         if btype == "cross":
@@ -221,7 +237,7 @@ def apply_block_decode(p, x, cache, pos, cfg, btype: str):
                                             window=window)
         x = x + a
         h = apply_norm(p["norm2"], x, cfg.norm)
-        f, drop = _ffn(p, h, cfg)
+        f, routed = _ffn_decode(p, h, cfg)
         if btype == "cross":
             f = f * jnp.tanh(p["gate_mlp"]).astype(f.dtype)
         x = x + f
@@ -234,11 +250,11 @@ def apply_block_decode(p, x, cache, pos, cfg, btype: str):
         y, new_cache = rglru_decode(p["rglru"], h, cache, cfg)
         x = x + y
         h = apply_norm(p["norm2"], x, cfg.norm)
-        f, drop = _ffn(p, h, cfg)
+        f, routed = _ffn_decode(p, h, cfg)
         x = x + f
     else:
         raise ValueError(btype)
-    return x, new_cache, drop
+    return x, new_cache, routed
 
 
 def apply_block_verify(p, x, cache, pos, cfg, btype: str):
@@ -253,9 +269,9 @@ def apply_block_verify(p, x, cache, pos, cfg, btype: str):
     a, new_cache = attention_verify(p["attn"], h, cache, pos, cfg)
     x = x + a
     h = apply_norm(p["norm2"], x, cfg.norm)
-    f, drop = _ffn(p, h, cfg)
+    f, routed = _ffn_decode(p, h, cfg)
     x = x + f
-    return x, new_cache, drop
+    return x, new_cache, routed
 
 
 def apply_stack_verify(stack, x, caches, pos, cfg):
@@ -336,30 +352,33 @@ def _draft_layer_slices(stack, caches, cfg, num_layers: int):
 
 
 def apply_stack_decode(stack, x, caches, pos, cfg):
-    """One-token decode through the whole stack; returns (x, new_caches, drop)."""
+    """One-token decode through the whole stack; returns (x, new_caches,
+    routed): for MoE the rows routed to each held expert, summed over the
+    layers, else a float zero."""
 
     def period_body(carry, inputs):
-        x, drop_acc = carry
+        x, acc = carry
         pp, pc = inputs
         new_pc = {}
         for i, btype in enumerate(cfg.block_pattern):
             x, c, d = apply_block_decode(pp[f"b{i}"], x, pc[f"b{i}"], pos, cfg,
                                          btype)
             new_pc[f"b{i}"] = c
-            drop_acc = drop_acc + d
-        return (x, drop_acc), new_pc
+            acc = acc + d
+        return (x, acc), new_pc
 
-    drop = jnp.float32(0)
+    routed = _routed0(cfg)
     if cfg.num_periods > 0:
         if cfg.scan_layers:
-            (x, drop), new_periods = jax.lax.scan(
-                period_body, (x, drop), (stack["periods"], caches["periods"]))
+            (x, routed), new_periods = jax.lax.scan(
+                period_body, (x, routed),
+                (stack["periods"], caches["periods"]))
         else:
             outs = []
             for i in range(cfg.num_periods):
                 pp = jax.tree_util.tree_map(lambda a: a[i], stack["periods"])
                 pc = jax.tree_util.tree_map(lambda a: a[i], caches["periods"])
-                (x, drop), npc = period_body((x, drop), (pp, pc))
+                (x, routed), npc = period_body((x, routed), (pp, pc))
                 outs.append(npc)
             new_periods = jax.tree_util.tree_map(
                 lambda *xs: jnp.stack(xs), *outs)
@@ -370,5 +389,5 @@ def apply_stack_decode(stack, x, caches, pos, cfg):
         x, c, d = apply_block_decode(stack["rest"][i], x, caches["rest"][i],
                                      pos, cfg, btype)
         new_rest.append(c)
-        drop = drop + d
-    return x, {"periods": new_periods, "rest": new_rest}, drop
+        routed = routed + d
+    return x, {"periods": new_periods, "rest": new_rest}, routed

@@ -60,7 +60,10 @@ request landed on after a ULFM shrink. This module adds that substrate:
   ``prefill_lanes`` fed a chunk, ``prompt_tokens`` fed), ``serve.wait``
   (the host blocked on the device: the window's wait and its token read;
   ``window``, ``ready`` if it was done at retirement), ``serve.commit``
-  (``window``, ``committed``, ``discarded`` tokens) and ``serve.recover``
+  (``window``, ``committed``, ``discarded`` tokens; for an MoE model also
+  ``moe_pairs``, the rows the window's lanes routed to the held experts,
+  summed over steps and layers, and ``moe_pairs_max``, the busiest held
+  expert's share of them) and ``serve.recover``
   (fault attribution and the LFLR lanes; ``window``). The step's host time
   is ``serve.step`` less its ``serve.wait``.
 

@@ -183,6 +183,11 @@ class _WindowInFlight:
     start_row: Optional[np.ndarray] = None
     rem0: Optional[np.ndarray] = None
     deferred: Optional[np.ndarray] = None
+    # MoE windows only. ``lanes``: lanes that ran a request (active and not
+    # deferred at dispatch). ``routed``: the window's (K, S, H) readback of
+    # rows routed to each held expert, set at retirement.
+    lanes: Optional[np.ndarray] = None
+    routed: Optional[np.ndarray] = None
     # tracing only: dispatch wall time + the window's index (_step_count at
     # dispatch), so the retire-side span covers the window's whole in-flight
     # life and fault events name the exact window they latched in.
@@ -1044,16 +1049,16 @@ class Replica:
                 self._dev_pos_dev = next_pos
                 outputs = (toks, counts)
             else:
-                toks, words, next_tok, caches = self._decode_window(
+                toks, words, *routed, next_tok, caches = self._decode_window(
                     self.params, self.caches, self._dev_tokens,
                     jnp.asarray(self._dev_pos), jnp.asarray(chunk),
                     jnp.asarray(rem), *extra)
-                outputs = toks
+                outputs = (toks, *routed) if routed else toks
         else:
-            toks, words, next_tok, caches = self._decode_window(
+            toks, words, *routed, next_tok, caches = self._decode_window(
                 self.params, self.caches, self._dev_tokens,
                 jnp.asarray(self._dev_pos), *extra)
-            outputs = toks
+            outputs = (toks, *routed) if routed else toks
         # the device-side chain advances: window N+1 consumes these directly
         self.caches = caches
         self._dev_tokens = next_tok
@@ -1078,6 +1083,7 @@ class Replica:
             start_row=start_row if self.speculate else None,
             rem0=rem0 if self.speculate else None,
             deferred=deferred if self.speculate else None,
+            lanes=mask.astype(bool) if self.cfg.is_moe else None,
             t_dispatch=t_disp, index=self._step_count,
             trace_ids=(tuple(s.req.trace_id if s.active else None
                              for s in sched.slots)
@@ -1103,10 +1109,13 @@ class Replica:
                                 faulted=exc is not None)
             # a faulted window's tokens are read too: its clean prefix commits
             block = jax.device_get(win.fut.outputs)
+        counts = None
         if self.speculate:
             toks, counts = (np.asarray(x) for x in block)
+        elif self.cfg.is_moe:
+            toks, win.routed = (np.asarray(x) for x in block)
         else:
-            toks, counts = np.asarray(block), None
+            toks = np.asarray(block)
         if exc is not None:
             return self._recover_window(win, exc, toks, counts)
         if self.speculate:
@@ -1231,6 +1240,12 @@ class Replica:
             if self.trace.enabled:
                 ph.note(window=win.index, committed=committed,
                         discarded=discarded)
+                if win.routed is not None:
+                    # rows routed to each held expert by the lanes that ran
+                    # a request, over the window's steps and the layers
+                    per = win.routed[:, win.lanes].sum(axis=(0, 1))
+                    ph.note(moe_pairs=int(per.sum()),
+                            moe_pairs_max=int(per.max()))
         return out
 
     def _recover_window(self, win: _WindowInFlight, exc: PropagatedError,

@@ -164,3 +164,24 @@ def test_decode_window_reads_bf16_cache_in_place(one_chip, name):
     assert not found, f"float32 copies of the KV cache: {found}"
     assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes, \
         "caches were not donated"
+
+
+def test_moe_decode_window_is_dropless_and_fits_one_chip(one_chip):
+    """qwen3-moe-30b-a3b-ep16 at its benchmark cell's shapes (32 slots, 1024
+    positions, K=8): the window fits the chip, and its expert layer has no
+    capacity buffer, neither the (..., experts, capacity, d) buffers nor the
+    flat (experts * capacity + 1, d) one the training path scatters tokens
+    into."""
+    from repro.models.moe import _capacity
+    cfg, compiled, _ = _compile_window(one_chip, "qwen3-moe-30b-a3b-ep16",
+                                       slots=32, max_len=1024)
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.alias_size_in_bytes > 0, "caches were not donated"
+    assert live < HBM_BYTES, f"window needs {live / 1e9:.1f} GB"
+    H, C, d = cfg.experts_held, _capacity(1, cfg), cfg.d_model
+    text = compiled.as_text()
+    buffers = re.findall(rf"\[(?:[0-9]+,)*(?:{H},{C}|{H * C + 1}),{d}\]", text)
+    assert not buffers, f"capacity buffers: {sorted(set(buffers))}"
+    assert " scatter(" not in text
