@@ -384,7 +384,7 @@ def init_kv_cache(batch: int, length: int, n_kv: int, head_dim: int, dtype):
 
 
 def attention_decode(p, x, cache, pos, cfg, *, window: int = 0,
-                     impl: str = "ref"):
+                     layer=None, impl: str = "ref"):
     """One-token decode. ``cache`` holds (k, v) of capacity T (full) or W (ring).
 
     pos: scalar int32 — global position of the new token. Sliding-window layers
@@ -392,6 +392,12 @@ def attention_decode(p, x, cache, pos, cfg, *, window: int = 0,
     via reconstructed slot positions, so the cache stays O(window) regardless of
     sequence length (this is what makes long_500k decode sub-quadratic AND
     sub-linear in memory for local layers).
+
+    layer: where ``cache`` is a stack of layers (leaves (L, B, cap, Kv, D)),
+    this layer's index in it. The new row is written into the stack at
+    ``(layer, slot)`` and the layer is then read by index, so attention sees
+    the same values as on one layer's cache; the whole stack is returned. A
+    layer scan that carries the stack thus writes one row a layer in place.
     """
     B = x.shape[0]
     hd = cfg.resolved_head_dim
@@ -402,10 +408,19 @@ def attention_decode(p, x, cache, pos, cfg, *, window: int = 0,
                        fraction=cfg.rope_fraction)
         k_new = apply_rope(k_new, posv, theta=cfg.rope_theta,
                            style=cfg.rope_style, fraction=cfg.rope_fraction)
-    cap = cache["k"].shape[1]
+    cap = cache["k"].shape[-3]
     slot = pos % cap if window else jnp.minimum(pos, cap - 1)
-    k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, 1)
-    v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, 1)
+    if layer is None:
+        k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, 1)
+        v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, 1)
+        new_cache = {"k": k, "v": v}
+    else:
+        at = (layer, 0, slot, 0, 0)
+        new_cache = {
+            "k": jax.lax.dynamic_update_slice(cache["k"], k_new[None], at),
+            "v": jax.lax.dynamic_update_slice(cache["v"], v_new[None], at)}
+        k, v = (jax.lax.dynamic_index_in_dim(new_cache[n], layer, 0,
+                                             keepdims=False) for n in "kv")
 
     # reconstruct the global position of every slot for masking
     slots = jnp.arange(cap)
@@ -423,7 +438,7 @@ def attention_decode(p, x, cache, pos, cfg, *, window: int = 0,
     probs = jax.nn.softmax(scores, axis=-1)
     out = _gqa_values(probs, v)
     out = jnp.einsum("bshe,hed->bsd", out.astype(x.dtype), p["wo"])
-    return out, {"k": k, "v": v}
+    return out, new_cache
 
 
 def attention_verify(p, x, cache, pos, cfg):

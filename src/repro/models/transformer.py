@@ -222,19 +222,44 @@ def init_stack_cache(batch, cfg, max_len: int, dtype):
     return {"periods": periods, "rest": rest}
 
 
-def apply_block_decode(p, x, cache, pos, cfg, btype: str):
-    """One-token block against its cache; returns (x, new_cache, routed)."""
+def _layer_of(cache, layer):
+    """This block's layer of a period stack of caches, read by index."""
+    if layer is None:
+        return cache
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        cache)
+
+
+def _put_layer(cache, new, layer):
+    """Write one layer's new cache into the period stack, in place."""
+    if layer is None:
+        return new
+    return jax.tree_util.tree_map(
+        lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, layer, 0),
+        cache, new)
+
+
+def apply_block_decode(p, x, cache, pos, cfg, btype: str, layer=None):
+    """One-token block against its cache; returns (x, new_cache, routed).
+
+    With ``layer``, ``cache`` is the period stack of this block's caches
+    (leading axis the period) and ``layer`` the block's index in it: the
+    block reads its layer by index and writes back only what it changed
+    (attention a K/V row, a recurrent block its state, cross attention
+    nothing), and returns the whole stack."""
     routed = _routed0(cfg)
     if btype in ATTN_KINDS:
         h = apply_norm(p["norm1"], x, cfg.norm)
         if btype == "cross":
-            a = cross_attention_decode(p["attn"], h, cache, cfg)
+            a = cross_attention_decode(p["attn"], h, _layer_of(cache, layer),
+                                       cfg)
             a = a * jnp.tanh(p["gate_attn"]).astype(a.dtype)
             new_cache = cache  # static image K/V
         else:
             window = cfg.sliding_window if btype == "sliding" else 0
             a, new_cache = attention_decode(p["attn"], h, cache, pos, cfg,
-                                            window=window)
+                                            window=window, layer=layer)
         x = x + a
         h = apply_norm(p["norm2"], x, cfg.norm)
         f, routed = _ffn_decode(p, h, cfg)
@@ -243,11 +268,13 @@ def apply_block_decode(p, x, cache, pos, cfg, btype: str):
         x = x + f
     elif btype == "ssd":
         h = apply_norm(p["norm1"], x, cfg.norm)
-        y, new_cache = mamba2_decode(p["ssd"], h, cache, cfg)
+        y, new = mamba2_decode(p["ssd"], h, _layer_of(cache, layer), cfg)
+        new_cache = _put_layer(cache, new, layer)
         x = x + y
     elif btype == "rglru":
         h = apply_norm(p["norm1"], x, cfg.norm)
-        y, new_cache = rglru_decode(p["rglru"], h, cache, cfg)
+        y, new = rglru_decode(p["rglru"], h, _layer_of(cache, layer), cfg)
+        new_cache = _put_layer(cache, new, layer)
         x = x + y
         h = apply_norm(p["norm2"], x, cfg.norm)
         f, routed = _ffn_decode(p, h, cfg)
@@ -277,9 +304,10 @@ def apply_block_verify(p, x, cache, pos, cfg, btype: str):
 def apply_stack_verify(stack, x, caches, pos, cfg):
     """T-token verify through the whole stack; returns (x, new_caches).
 
-    Structure mirrors :func:`apply_stack_decode` exactly (same period scan,
-    same remainder unroll) with the multi-token verify block, so each token
-    row computes the single-token decode arithmetic at its own position.
+    Structure mirrors :func:`apply_stack_decode` (same layer order, same
+    remainder unroll) with the multi-token verify block, so each token row
+    computes the single-token decode arithmetic at its own position. It still
+    scans the period caches as ``xs`` / ``ys``.
     """
 
     def period_body(x, inputs):
@@ -354,40 +382,42 @@ def _draft_layer_slices(stack, caches, cfg, num_layers: int):
 def apply_stack_decode(stack, x, caches, pos, cfg):
     """One-token decode through the whole stack; returns (x, new_caches,
     routed): for MoE the rows routed to each held expert, summed over the
-    layers, else a float zero."""
+    layers, else a float zero.
+
+    The period stacks of caches ride in the scan's carry, not its ``xs`` /
+    ``ys``: each block reads its layer by index and writes one row (or its
+    state) back in place. Scanned caches would be sliced into the body and
+    re-stacked out of it every step, and under the serving engine's vmap over
+    slot-major caches, leaves (S, L, ...), also transposed whole to
+    layer-major and back."""
 
     def period_body(carry, inputs):
-        x, acc = carry
-        pp, pc = inputs
-        new_pc = {}
+        x, acc, pc = carry
+        pp, c = inputs
         for i, btype in enumerate(cfg.block_pattern):
-            x, c, d = apply_block_decode(pp[f"b{i}"], x, pc[f"b{i}"], pos, cfg,
-                                         btype)
-            new_pc[f"b{i}"] = c
+            key = f"b{i}"
+            x, new, d = apply_block_decode(pp[key], x, pc[key], pos, cfg,
+                                           btype, layer=c)
+            pc = {**pc, key: new}
             acc = acc + d
-        return (x, acc), new_pc
+        return (x, acc, pc), None
 
     routed = _routed0(cfg)
+    periods = caches["periods"]
     if cfg.num_periods > 0:
         if cfg.scan_layers:
-            (x, routed), new_periods = jax.lax.scan(
-                period_body, (x, routed),
-                (stack["periods"], caches["periods"]))
+            (x, routed, periods), _ = jax.lax.scan(
+                period_body, (x, routed, periods),
+                (stack["periods"], jnp.arange(cfg.num_periods)))
         else:
-            outs = []
-            for i in range(cfg.num_periods):
-                pp = jax.tree_util.tree_map(lambda a: a[i], stack["periods"])
-                pc = jax.tree_util.tree_map(lambda a: a[i], caches["periods"])
-                (x, routed), npc = period_body((x, routed), (pp, pc))
-                outs.append(npc)
-            new_periods = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *outs)
-    else:
-        new_periods = caches["periods"]
+            for c in range(cfg.num_periods):
+                pp = jax.tree_util.tree_map(lambda a: a[c], stack["periods"])
+                (x, routed, periods), _ = period_body((x, routed, periods),
+                                                      (pp, c))
     new_rest = []
     for i, btype in enumerate(cfg.remainder_layers):
         x, c, d = apply_block_decode(stack["rest"][i], x, caches["rest"][i],
                                      pos, cfg, btype)
         new_rest.append(c)
         routed = routed + d
-    return x, {"periods": new_periods, "rest": new_rest}, routed
+    return x, {"periods": periods, "rest": new_rest}, routed

@@ -185,3 +185,45 @@ def test_moe_decode_window_is_dropless_and_fits_one_chip(one_chip):
     buffers = re.findall(rf"\[(?:[0-9]+,)*(?:{H},{C}|{H * C + 1}),{d}\]", text)
     assert not buffers, f"capacity buffers: {sorted(set(buffers))}"
     assert " scatter(" not in text
+
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = \w+\[([0-9,]*)\]\S* ([\w-]+)\(", re.M)
+
+
+@pytest.mark.parametrize("name,slots,max_len,temp_gb", [
+    ("qwen3-1.7b", 16, 2048, 1.0),
+    ("starcoder2-3b", 16, 2048, 1.0),
+    ("qwen3-moe-30b-a3b-ep16", 32, 1024, 1.5),
+])
+def test_decode_window_moves_no_whole_cache(one_chip, name, slots, max_len,
+                                            temp_gb):
+    """At each benchmark cell's shapes the layer scan carries the slot-major
+    period caches, leaves (S, L, 1, cap, Kv, D), and writes one row a layer
+    in place: no copy, transpose or write-back builds a layer-major
+    (L, S, 1, cap, Kv, D) stack, no copy or transpose moves a whole
+    slot-major stack (the in-place row writes may output one), the
+    temporaries stay small, and the caches stay donated."""
+    from repro.models import build_model
+    cfg, compiled, cache_bytes = _compile_window(one_chip, name, slots,
+                                                 max_len)
+    periods = build_model(cfg).cache_shapes(1, max_len)["periods"]
+    leaves = [x.shape for x in jax.tree_util.tree_leaves(periods)
+              if x.ndim == 5]
+    assert leaves, f"{name} has no period-stacked KV cache"
+    slot_major = {(slots, *s) for s in leaves}
+    layer_major = {(s[0], slots, *s[1:]) for s in leaves}
+    moved = []
+    for instr, shape, op in _INSTRUCTION.findall(compiled.as_text()):
+        dims = tuple(int(d) for d in shape.split(",") if d)
+        relayout = (op in ("copy", "transpose")
+                    or re.search("copy|transpose", instr))
+        write_back = "dynamic-update-slice" in op + instr
+        if ((dims in layer_major and (relayout or write_back))
+                or (dims in slot_major and relayout)):
+            moved.append(f"{instr} {op} {dims}")
+    assert not moved, f"whole-cache moves: {moved}"
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < temp_gb * 1e9, \
+        f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB"
+    assert mem.alias_size_in_bytes >= cache_bytes, "caches were not donated"
