@@ -43,3 +43,37 @@ def count_syncs(monkeypatch, fn):
         monkeypatch.setattr(jax, "device_get", real_get)
         monkeypatch.setattr(jax, "block_until_ready", real_block)
     return counts["n"], result
+
+
+_REFERENCE_PREFILLS: dict = {}
+
+
+def greedy_reference(cfg, params, requests, max_len, eos_id=None):
+    """Greedy token streams that depend on no serving engine: for each
+    request, ``make_cache_prefill`` over the prompt plus the tokens so far,
+    then argmax — until ``max_new_tokens`` or ``eos_id``. Returns
+    ``{request id: tokens}``; every engine test compares against this."""
+    import numpy as np
+
+    from repro.launch.steps import make_cache_prefill
+    from repro.serve.engine import SERVE_PROBES
+
+    key = (id(cfg), max_len)
+    if key not in _REFERENCE_PREFILLS:
+        # the fused prefill compiles once per (model, capacity); keep cfg
+        # alive so its id cannot be reused by another config
+        _REFERENCE_PREFILLS[key] = (cfg, make_cache_prefill(
+            cfg, SERVE_PROBES, fused=True))
+    prefill = _REFERENCE_PREFILLS[key][1]
+    out = {}
+    for req in requests:
+        toks: list[int] = []
+        while len(toks) < req.max_new_tokens:
+            seq = np.asarray([list(req.prompt) + toks], np.int32)
+            logits, _, word = prefill(params, seq, max_len)
+            assert int(word) == 0, f"reference prefill faulted: {int(word)}"
+            toks.append(int(np.argmax(np.asarray(logits)[0, -1])))
+            if eos_id is not None and toks[-1] == eos_id:
+                break
+        out[req.id] = tuple(toks)
+    return out

@@ -165,7 +165,8 @@ class DeviceFuture:
     ``outputs`` stay asynchronous; ``wait`` synchronises on the 4-byte error word
     (plus the optional enumeration table) and converts it to the paper's exceptions.
 
-    **Window semantics** (decode windows, ``launch.steps.make_decode_window``):
+    **Window semantics** (decode windows,
+    ``launch.steps.make_prefill_decode_window``):
     a future may cover K deferred steps at once. ``word`` is then the OR over
     the whole window — checked once per K tokens, not per token — and
     ``history`` holds the ``(K, ranks)`` per-step per-rank word matrix so that

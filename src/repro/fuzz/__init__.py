@@ -1,8 +1,9 @@
 """Coverage-guided fault-injection fuzzer for the serving stack.
 
-Drives the real engines (stepwise / windowed / overlapped / paged /
-speculative replicas, the ULFM ServeGroup, and the multihost real-process
-fault domain) end to end with seeded, fully reproducible fault trajectories; measures coverage over the derived
+Drives the real engines (the overlapped window replica and its paged /
+tensor-parallel / speculative variants, the ULFM ServeGroup, and the
+multihost real-process fault domain) end to end with seeded, fully
+reproducible fault trajectories; measures coverage over the derived
 (error code × recovery action × engine) matrix; judges every run against
 the stack's own contracts (bit-exactness, zero drops, ledger invariants,
 trace causality); and minimizes + promotes every counterexample into the

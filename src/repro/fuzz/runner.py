@@ -37,7 +37,6 @@ import os
 import shutil
 import tempfile
 from dataclasses import dataclass, field
-from typing import Optional
 
 import jax
 import numpy as np
@@ -45,21 +44,14 @@ import numpy as np
 from ..configs import smoke_config
 from ..core.errors import ErrorCode
 from ..core.faults import FaultSchedule, FaultSpec
-from ..launch.paging import PagedLayout
-from ..launch.steps import (
-    make_cache_prefill,
-    make_decode_window,
-    make_prefill_decode_window,
-    make_slot_decode_step,
-    make_speculative_decode_window,
-)
 from ..models import build_model
 from ..obs import postmortem
 from ..obs.trace import Tracer, merge_trace_dicts, merge_traces
 from ..serve.config import EngineConfig
+from ..serve.engine import EnginePrograms, build_engine
 from ..serve.group import ServeGroup
 from ..serve.queue import FAILED, OK, Request
-from ..serve.replica import SERVE_PROBES, Replica
+from ..serve.replica import Replica
 from ..serve.multihost import MultiHostSupervisor
 from .coverage import Cell
 from .trajectory import GROUP_ENGINE, MULTIHOST_ENGINE, Op, Trajectory
@@ -82,8 +74,7 @@ class EngineSpec:
     """Replica-shape knobs for one engine variant (kept tiny: the fuzzer's
     job is path coverage, not throughput)."""
 
-    window: int = 0
-    overlap: bool = False
+    window: int = 4
     paged: bool = False
     page_size: int = 8
     speculate: bool = False
@@ -98,42 +89,35 @@ class EngineSpec:
         return EngineConfig(
             num_slots=self.num_slots, max_len=self.max_len,
             max_request_retries=max_request_retries, window=self.window,
-            overlap=self.overlap, paged=self.paged, page_size=self.page_size,
+            paged=self.paged, page_size=self.page_size,
             speculate=self.speculate, draft_len=self.draft_len,
             draft_layers=self.draft_layers, tp=self.tp)
 
 
 ENGINE_SPECS: dict[str, EngineSpec] = {
-    "stepwise": EngineSpec(),
-    "window": EngineSpec(window=4, overlap=False),
-    "overlap": EngineSpec(window=4, overlap=True),
+    "overlap": EngineSpec(),
     # tp=2 on forced host devices (conftest / the fuzz CLI set XLA_FLAGS):
     # same window shape as "overlap", so any divergence between the two
     # engines' streams is the cross-shard machinery's fault, nothing else's
-    "overlap_tp": EngineSpec(window=4, overlap=True, tp=2),
-    "overlap_paged": EngineSpec(window=4, overlap=True, paged=True,
-                                page_size=8),
-    "spec": EngineSpec(window=4, overlap=True, speculate=True, max_len=64),
-    "spec_paged": EngineSpec(window=4, overlap=True, speculate=True,
-                             paged=True, page_size=16, max_len=64),
+    "overlap_tp": EngineSpec(tp=2),
+    "overlap_paged": EngineSpec(paged=True, page_size=8),
+    "spec": EngineSpec(speculate=True, max_len=64),
+    "spec_paged": EngineSpec(speculate=True, paged=True, page_size=16,
+                             max_len=64),
 }
 
 
 @dataclass(frozen=True)
 class EngineKit:
-    """Shared, compile-once state for one engine variant: the jitted step
-    functions every Replica built from this kit reuses (same sharing contract
-    as ServeGroup — make_* factories return fresh closures, so letting each
-    Replica build its own would recompile per trajectory)."""
+    """Shared, compile-once state for one engine variant: the programs every
+    Replica built from this kit reuses (same sharing contract as ServeGroup
+    — letting each Replica build its own would recompile per trajectory)."""
 
     engine: str
     spec: EngineSpec
     cfg: object
     params: object
-    decode_fn: object
-    prefill_fn: object
-    window_fn: object
-    layout: Optional[PagedLayout]
+    programs: EnginePrograms
 
 
 @functools.lru_cache(maxsize=None)
@@ -143,59 +127,14 @@ def _env():
     return cfg, params
 
 
-def _tp_ctx(cfg, params, spec: EngineSpec):
-    """The kit-shared TPContext (mesh + storage specs) for a TP variant —
-    the same derivation ServeGroup/Replica perform, done once per kit.
-    Raises early when the process lacks the devices (the fuzz CLI and the
-    test conftest force host devices via XLA_FLAGS)."""
-    from ..launch.steps import TPContext
-    from ..sharding.rules import param_specs, tp_storage_specs
-    ndev = len(jax.devices())
-    if ndev < spec.tp:
-        raise ValueError(
-            f"tp={spec.tp} requires {spec.tp} devices, found {ndev} "
-            "(force host devices with XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={spec.tp})")
-    mesh = jax.make_mesh((spec.tp,), ("model",))
-    one = build_model(cfg).init_cache(1, spec.max_len)
-    stacked = jax.tree_util.tree_map(
-        lambda v: jax.ShapeDtypeStruct((spec.num_slots, *v.shape), v.dtype),
-        one)
-    return TPContext(mesh=mesh, param_specs=param_specs(params, mesh),
-                     cache_specs=tp_storage_specs(stacked, mesh))
-
-
 @functools.lru_cache(maxsize=None)
 def get_kit(engine: str) -> EngineKit:
     cfg, params = _env()
     spec = ENGINE_SPECS[engine]
-    layout = None
-    if spec.paged:
-        num_pages = spec.num_slots * (spec.max_len // spec.page_size)
-        layout = PagedLayout(build_model(cfg).init_cache(1, spec.max_len),
-                             spec.max_len, page_size=spec.page_size,
-                             num_pages=num_pages)
-    tp = _tp_ctx(cfg, params, spec) if spec.tp > 1 else None
-    decode_fn = jax.jit(make_slot_decode_step(cfg, SERVE_PROBES))
-    prefill_fn = make_cache_prefill(cfg, SERVE_PROBES,
-                                    fused=bool(spec.window), paged=layout,
-                                    donate=bool(spec.paged))
-    if not spec.window:
-        window_fn = None
-    elif spec.speculate:
-        window_fn = make_speculative_decode_window(
-            cfg, SERVE_PROBES, window=spec.window, draft_len=spec.draft_len,
-            draft_layers=spec.draft_layers, paged=layout, tp=tp)
-    elif spec.overlap:
-        window_fn = make_prefill_decode_window(cfg, SERVE_PROBES,
-                                               window=spec.window,
-                                               paged=layout, tp=tp)
-    else:
-        window_fn = make_decode_window(cfg, SERVE_PROBES, window=spec.window,
-                                       paged=layout, tp=tp)
+    # retries do not shape the programs: any value builds the same kit
+    programs = build_engine(cfg, params, spec.engine_config(0))
     return EngineKit(engine=engine, spec=spec, cfg=cfg, params=params,
-                     decode_fn=decode_fn, prefill_fn=prefill_fn,
-                     window_fn=window_fn, layout=layout)
+                     programs=programs)
 
 
 @functools.lru_cache(maxsize=None)
@@ -204,8 +143,7 @@ def _group_kit(max_request_retries: int,
     cfg, _ = _env()
     return ServeGroup(cfg, nranks=GROUP_RANKS, max_ranks=max_ranks,
                       config=EngineConfig(
-                          num_slots=2, max_len=32, window=4, overlap=True,
-                          eos_id=None,
+                          num_slots=2, max_len=32, window=4, eos_id=None,
                           max_request_retries=max_request_retries,
                           trace=True))
 
@@ -227,9 +165,7 @@ class _ScheduledInjector:
             return None
         w = np.zeros(shape, np.uint32)
         for op in ops:
-            if len(shape) == 1:               # stepwise: (slots,)
-                w[op.slot % shape[0]] |= np.uint32(op.code)
-            elif len(shape) == 2:             # windowed: (K, slots)
+            if len(shape) == 2:               # windowed: (K, slots)
                 w[op.step % shape[0], op.slot % shape[1]] |= np.uint32(op.code)
             else:                             # TP windowed: (tp, K, slots)
                 shard = (slice(None) if op.shard < 0
@@ -355,9 +291,7 @@ def _run_single(traj: Trajectory, *, reference: dict,
     tracer = Tracer(pid=0)
     rep = Replica(kit.cfg, params=kit.params,
                   config=spec.engine_config(traj.max_request_retries),
-                  decode_fn=kit.decode_fn, prefill_fn=kit.prefill_fn,
-                  window_fn=kit.window_fn, paged_layout=kit.layout,
-                  tracer=tracer,
+                  programs=kit.programs, tracer=tracer,
                   fault_injector=_ScheduledInjector(traj.ops_of("word")),
                   page_debug=True)
     res = RunResult(trajectory=traj)

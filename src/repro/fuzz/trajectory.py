@@ -62,8 +62,8 @@ HOST_OPS = frozenset({"host_kill", "host_stop"})
 #: ULFM engine; ``multihost`` is the real-process fault domain (subprocess
 #: workers under the heartbeat supervisor); the rest are single-replica
 #: serving code paths.
-SINGLE_ENGINES = ("stepwise", "window", "overlap", "overlap_tp",
-                  "overlap_paged", "spec", "spec_paged")
+SINGLE_ENGINES = ("overlap", "overlap_tp", "overlap_paged", "spec",
+                  "spec_paged")
 GROUP_ENGINE = "group"
 MULTIHOST_ENGINE = "multihost"
 ENGINES = SINGLE_ENGINES + (GROUP_ENGINE, MULTIHOST_ENGINE)

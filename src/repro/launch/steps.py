@@ -45,31 +45,6 @@ class PerfOptions:
                       (B,H) statistics instead of (B,H,T) score tensors).
     probes          — the in-band device channel on/off (off only for overhead
                       measurement — never in production).
-    window          — decode-window size K for serving: scan K fused slot-decode
-                      steps fully on device with deferred fault detection
-                      (``make_decode_window``); 0 = per-token decode.
-    donate          — donate caches/slot state to the decode window so XLA
-                      updates them in place (no per-window cache copy).
-    overlap         — fuse admission/LFLR prefill into the decode windows
-                      (``make_prefill_decode_window``): joining or recovering
-                      sequences advance their cache by a prompt chunk *inside*
-                      the window scan, so prefill never stalls the token
-                      stream; ignored when ``window == 0``.
-    page            — paged KV pool page size for serving (``launch.paging``):
-                      full-attention caches become a shared page pool addressed
-                      through a per-slot page table, so long prompts and short
-                      chats share HBM; 0 = one contiguous block per slot.
-    speculate       — speculative decode windows (``make_speculative_decode_
-                      window``): each window step drafts ``draft_len`` tokens
-                      with a shallow-exit self-draft over the first
-                      ``draft_layers`` layers, then verifies all drafts in one
-                      batched full-model forward — up to ``draft_len + 1``
-                      tokens per full-model step, token-bit-exact vs the plain
-                      window engine; rejected drafts are attributed in-band
-                      via ``ErrorCode.DRAFT_REJECT``. Requires ``window > 0``
-                      and a pure full-attention architecture.
-    draft_len       — tokens proposed per speculative window step (D).
-    draft_layers    — layers of the shallow-exit drafter.
     """
 
     microbatch: int = 0
@@ -78,18 +53,13 @@ class PerfOptions:
     cache_seq_model: bool = False
     probes: bool = True
     ep_constraint: bool = False   # MoE dispatch buffers constrained E-over-model
-    window: int = 0
-    donate: bool = True
-    overlap: bool = True
-    page: int = 0
-    speculate: bool = False
-    draft_len: int = 3
-    draft_layers: int = 1
 
     @classmethod
     def parse(cls, spec: str) -> "PerfOptions":
-        """'mb=8,ce=2048,sp=1,cacheseq=1,probes=0,ep=1,window=8,donate=1,
-        overlap=1,page=16,spec=1,dlen=3,dlayers=1' → PerfOptions."""
+        """'mb=8,ce=2048,sp=1,cacheseq=1,probes=0,ep=1' → PerfOptions.
+
+        Serving-engine shape is not a PerfOptions concern: it lives in
+        :class:`repro.serve.EngineConfig`."""
         kw: dict = {}
         for part in (spec or "").split(","):
             if not part:
@@ -97,15 +67,10 @@ class PerfOptions:
             k, v = part.split("=")
             k = {"mb": "microbatch", "ce": "ce_chunk", "sp": "seq_shard",
                  "cacheseq": "cache_seq_model", "probes": "probes",
-                 "ep": "ep_constraint", "win": "window", "window": "window",
-                 "donate": "donate", "overlap": "overlap",
-                 "page": "page", "spec": "speculate", "speculate": "speculate",
-                 "dlen": "draft_len", "draft_len": "draft_len",
-                 "dlayers": "draft_layers", "draft_layers": "draft_layers"}[k]
+                 "ep": "ep_constraint"}[k]
             kw[k] = bool(int(v)) if k in ("seq_shard", "cache_seq_model",
-                                          "probes", "ep_constraint",
-                                          "donate", "overlap",
-                                          "speculate") else int(v)
+                                          "probes",
+                                          "ep_constraint") else int(v)
         return cls(**kw)
 
 
@@ -394,28 +359,39 @@ def _tp_window(body, tp: TPContext, *, n_rest: int, words_index: int,
     return jax.jit(mapped, donate_argnums=(1,) if donate else ())
 
 
-def make_decode_window(cfg: ModelConfig, probe_cfg: ProbeConfig | None = None,
-                       *, window: int, donate: bool = True, paged=None,
-                       tp: TPContext | None = None):
-    """Pipelined decode window: K fused slot-decode steps in one device program.
+def make_prefill_decode_window(cfg: ModelConfig,
+                               probe_cfg: ProbeConfig | None = None, *,
+                               window: int, donate: bool = True, paged=None,
+                               tp: TPContext | None = None):
+    """The serving window: K fused slot-decode steps in one device program,
+    with chunked prefill riding the same scan.
 
-    The serving hot path must not pay a host-device round trip per token — the
-    paper's asynchrony contract (errors latch in-band and raise at the *wait*,
-    not eagerly at every operation) applied to decoding. ``lax.scan`` runs
-    ``window`` iterations of :func:`make_slot_decode_step` fully on device:
-    greedy argmax is computed *inside* the scan and fed back as the next input
-    token, so the token chain never touches the host; per-step per-slot error
-    words are stacked into a ``(K, slots)`` history so the host can defer fault
-    detection to the window boundary and still attribute a fault to its exact
-    ``(step, slot)`` (LFLR replays greedy from the last committed boundary —
-    deterministic, hence bit-exact).
+    The serving hot path must not pay a host-device round trip per token —
+    the paper's asynchrony contract (errors latch in-band and raise at the
+    *wait*, not eagerly at every operation) applied to decoding.
+    ``lax.scan`` runs ``window`` iterations of :func:`make_slot_decode_step`
+    fully on device: greedy argmax is computed *inside* the scan and fed
+    back as the next input token, so the token chain never touches the host;
+    per-step per-slot error words are stacked into a ``(K, slots)`` history
+    so the host can defer fault detection to the window boundary and still
+    attribute a fault to its exact ``(step, slot)``.
+
+    Admission and LFLR re-prefill ride the same scan (Sarathi-style chunking
+    folded into the asynchrony contract): decoding slots advance by greedy
+    feedback while a joining or recovering slot consumes up to K tokens of
+    its prompt chunk — a per-slot ``jnp.where`` on the input token — so no
+    prefill ever stalls the healthy slots.
 
     Signature of the returned jitted function::
 
-      window_step(params, caches, tokens, pos)
+      window_step(params, caches, tokens, pos, chunk, rem)
         caches  pytree, leaves (S, ...)   donated when ``donate`` (in-place)
-        tokens  (S, 1, 1) int32           input token per slot
+        tokens  (S, 1, 1) int32           greedy feedback feed per slot
         pos     (S,) int32                per-slot absolute position
+        chunk   (K, S) int32              prompt tokens to feed per step × slot
+        rem     (S,) int32                prompt-feed steps for each slot:
+                                          step k consumes ``chunk[k, s]`` iff
+                                          ``k < rem[s]``, else greedy feedback
       → (tokens (K, S) int32,             greedy token emitted per step × slot
          words  (K, S) uint32,            per-(step, slot) error-word history
          [routed (K, S, H) int32,]        MoE only: rows of each step × slot
@@ -428,114 +404,27 @@ def make_decode_window(cfg: ModelConfig, probe_cfg: ProbeConfig | None = None,
     reading back window N's token block (double-buffered commit loop): the
     chain's data dependencies live entirely on device.
 
+    Flip semantics: when a chunk exhausts a slot's prompt at step ``rem-1``,
+    that step's argmax — the logits after the *last* prompt token — is the
+    sequence's first generated token, and steps ``rem .. K-1`` continue
+    greedy decode for it in the same window; the host knows that only steps
+    ``>= rem-1`` of that lane's token block are real. A fault latched during
+    a chunk lands in the same ``(K, slots)`` word history as decode faults
+    and is attributed to its exact ``(step, slot)`` — recovery re-queues the
+    lane without ever blocking the host.
+
     With ``paged`` (a :class:`~repro.launch.paging.PagedLayout`) the caches
     argument is the hybrid pool tree and the function takes a trailing
     ``table (S, max_pages) int32`` page-table argument; gather/scatter page
-    addressing runs *inside* the window scan, so the zero-sync on-device
-    token chain is untouched and the produced tokens are bit-exact vs the
-    contiguous layout.
+    addressing runs *inside* the window scan, so the zero-sync token chain
+    is untouched and the produced tokens are bit-exact vs the contiguous
+    layout.
 
     With ``tp`` (a :class:`TPContext`) the whole window is shard_mapped over
     the "model" mesh axis (:func:`_tp_window`): params/caches are passed as
     their per-shard storage slices, the function takes one extra trailing
     ``inj (tp, K, S) uint32`` per-shard injection argument, and the returned
     word history is the cross-shard OR-fold.
-    """
-    if window < 1:
-        raise ValueError(f"window must be >= 1, got {window}")
-    routed = cfg.is_moe
-    slot_step = make_slot_decode_step(cfg, probe_cfg, routed=routed)
-
-    if paged is not None:
-        pstep = _paged_slot_step(slot_step, paged)
-
-        def paged_window_step(params, hybrid, tokens, pos, table):
-            def body(carry, _):
-                hybrid, tok, p = carry
-                logits, hybrid, words, *pairs = pstep(params, hybrid, tok, p,
-                                                      table)
-                nxt = jnp.argmax(logits[:, 0, 0, :], axis=-1).astype(jnp.int32)
-                return (hybrid, nxt[:, None, None], p + 1), (nxt, words,
-                                                             *pairs)
-
-            (hybrid, next_tok, _), (toks, words, *pairs) = jax.lax.scan(
-                body, (hybrid, jnp.asarray(tokens, jnp.int32),
-                       jnp.asarray(pos, jnp.int32)), None, length=window)
-            return (toks, words.astype(jnp.uint32), *pairs, next_tok,
-                    hybrid)
-
-        if tp is not None:
-            return _tp_window(paged_window_step, tp, n_rest=3,
-                              words_index=1, n_out=4 + routed, donate=donate)
-        return jax.jit(paged_window_step,
-                       donate_argnums=(1,) if donate else ())
-
-    def window_step(params, caches, tokens, pos):
-        def body(carry, _):
-            caches, tok, p = carry
-            logits, caches, words, *pairs = slot_step(params, caches, tok, p)
-            nxt = jnp.argmax(logits[:, 0, 0, :], axis=-1).astype(jnp.int32)
-            return (caches, nxt[:, None, None], p + 1), (nxt, words, *pairs)
-
-        (caches, next_tok, _), (toks, words, *pairs) = jax.lax.scan(
-            body, (caches, jnp.asarray(tokens, jnp.int32),
-                   jnp.asarray(pos, jnp.int32)), None, length=window)
-        return (toks, words.astype(jnp.uint32), *pairs, next_tok, caches)
-
-    if tp is not None:
-        return _tp_window(window_step, tp, n_rest=2, words_index=1,
-                          n_out=4 + routed, donate=donate)
-    return jax.jit(window_step, donate_argnums=(1,) if donate else ())
-
-
-def make_prefill_decode_window(cfg: ModelConfig,
-                               probe_cfg: ProbeConfig | None = None, *,
-                               window: int, donate: bool = True, paged=None,
-                               tp: TPContext | None = None):
-    """Fused decode+prefill window: chunked prefill rides the decode scan.
-
-    The last synchronous edge of the serving pipeline is admission / LFLR
-    re-prefill: a full-length blocking prefill between windows freezes every
-    healthy slot while one slot joins or recovers. This window step makes
-    prefill a first-class citizen of the decode window (Sarathi-style chunking
-    folded into the paper's asynchrony contract): inside the *same*
-    ``lax.scan`` dispatch, decoding slots advance by greedy feedback while a
-    joining/recovering slot consumes up to K tokens of its prompt chunk —
-    per-slot ``jnp.where`` on the input token is the only difference from
-    :func:`make_decode_window`, so a window with no chunk is computation-
-    identical (bit-exact) to the decode-only window.
-
-    Signature of the returned jitted function::
-
-      window_step(params, caches, tokens, pos, chunk, rem)
-        caches  pytree, leaves (S, ...)   donated when ``donate``
-        tokens  (S, 1, 1) int32           greedy feedback feed per slot
-        pos     (S,) int32                per-slot absolute position
-        chunk   (K, S) int32              prompt tokens to feed per step × slot
-        rem     (S,) int32                prompt-feed steps for each slot:
-                                          step k consumes ``chunk[k, s]`` iff
-                                          ``k < rem[s]``, else greedy feedback
-      → (tokens (K, S), words (K, S), [routed (K, S, H),] next_tok (S, 1, 1),
-         new caches)
-
-    ``routed`` (MoE only) as in :func:`make_decode_window`.
-
-    Flip semantics: when a chunk exhausts a slot's prompt at step ``rem-1``,
-    that step's argmax — the logits after the *last* prompt token — is the
-    sequence's first generated token, and steps ``rem .. K-1`` continue greedy
-    decode for it in the same window. This is exactly the computation the
-    synchronous path performs (prefill logits → argmax → feed back), so the
-    trajectory is bit-exact vs blocking admission; the host simply knows that
-    only steps ``>= rem-1`` of that lane's token block are real. A fault
-    latched during a chunk lands in the same ``(K, slots)`` word history as
-    decode faults and is attributed to its exact ``(step, slot)`` — recovery
-    re-queues the lane without ever blocking the host.
-
-    With ``paged`` the caches argument is the hybrid pool tree and the
-    function takes a trailing ``table`` page-table argument (see
-    :func:`make_decode_window`); a chunking lane writes its prompt through
-    the same gather/scatter addressing, so admission and LFLR page
-    re-acquisition ride the window exactly like the contiguous engine.
     """
     if window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
@@ -605,8 +494,8 @@ def make_speculative_decode_window(cfg: ModelConfig,
                                    paged=None, tp: TPContext | None = None):
     """Speculative decode window: draft-and-verify inside one dispatch.
 
-    The zero-sync window (:func:`make_decode_window`) pays one full-model
-    forward per emitted token. This window makes the *emission rate* exceed
+    The plain window (:func:`make_prefill_decode_window`) pays one
+    full-model forward per emitted token. This window makes the *emission rate* exceed
     the full-model step rate while keeping the paper's asynchrony contract:
     each of the K window steps
 
@@ -872,72 +761,28 @@ def make_chunked_prefill(cfg: ModelConfig,
 
 
 def make_cache_prefill(cfg: ModelConfig, probe_cfg: ProbeConfig | None = None,
-                       *, fused: bool = False, paged=None,
-                       donate: bool = False):
+                       *, fused: bool = False):
     """Cache-producing prefill built by reusing the decode step.
 
     Returns ``prefill(params, tokens, max_len, start_pos=0)`` for ``tokens``
     of shape (B, S) → ``(last-position logits, cache, combined error word)``.
 
-    This is the recompute path of serving LFLR: re-running it over
-    prompt + generated tokens rebuilds a poisoned sequence's state exactly
-    (greedy decode is deterministic), so recovery never restarts the request.
+    Re-running it over prompt + generated tokens rebuilds a sequence's state
+    exactly (greedy decode is deterministic): the engine-independent greedy
+    reference the serving tests compare every engine against.
 
-    Two implementations, both token-by-token through the *same* decode step
-    (sharing the step is what makes the LFLR recompute reproduce the batched
-    trajectory exactly):
+    Two implementations, both token-by-token through the *same* decode step:
 
-    * ``fused=False`` — a host loop of S jitted step dispatches (the PR-1
-      path: simple, one compile, but S dispatch overheads per prefill);
+    * ``fused=False`` — a host loop of S jitted step dispatches (simple, one
+      compile, but S dispatch overheads per prefill);
     * ``fused=True``  — one jitted ``lax.fori_loop`` whose trip count is the
       *traced* real length: tokens are padded to the (static) cache capacity
-      so one compile serves every prompt/recompute length, but only the real
-      steps execute — no wasted padded iterations, no masking, and the body
-      is the same decode step, so the result is bit-identical to the loop.
-      This is the serving window engine's admission/LFLR path: one dispatch
-      per prefill instead of S.
-
-    With ``paged`` the signature becomes ``prefill(params, hybrid, row, slot,
-    tokens, start_pos=0)`` (``fused`` implied): the rebuilt cache is written
-    straight into the slot's pool pages through its page-table ``row``, after
-    an in-program scrub of those pages and a fresh reset of the slot's dense
-    state — the whole blocking re-prefill is one dispatch and never leaves
-    stale (possibly poisoned) bytes behind in a recycled page.
+      so one compile serves every prompt length, but only the real steps
+      execute — no wasted padded iterations, no masking, and the body is the
+      same decode step, so the result is bit-identical to the loop.
     """
     model = build_model(cfg)
     step_fn = make_decode_step(cfg, probe_cfg)
-
-    if paged is not None:
-        # donate: the hybrid argument is the FULL multi-slot pool — an
-        # out-of-place update here would transiently double the very HBM the
-        # paged layout exists to save (the caller must rebind its pool to the
-        # returned tree before any retry)
-        chunked = make_chunked_prefill(cfg, probe_cfg, chunk=paged.max_len,
-                                       paged=paged, donate=donate)
-
-        @functools.partial(jax.jit, donate_argnums=(0,) if donate else ())
-        def fresh_slot(hybrid, row, slot):
-            hybrid = paged.scrub(hybrid, row)
-            return paged.reset_slot(hybrid, model.init_cache(1, paged.max_len),
-                                    slot)
-
-        def prefill(params, hybrid, row, slot, tokens, start_pos: int = 0):
-            tokens = jnp.asarray(tokens, jnp.int32)
-            if tokens.ndim != 2 or tokens.shape[1] == 0:
-                raise ValueError(f"tokens must be (B, S>0), got {tokens.shape}")
-            _, S = tokens.shape
-            if S > paged.max_len:
-                raise ValueError(
-                    f"prompt of {S} tokens exceeds capacity {paged.max_len}")
-            hybrid = fresh_slot(hybrid, jnp.asarray(row, jnp.int32),
-                                jnp.int32(slot))
-            padded = jnp.pad(tokens, ((0, 0), (0, paged.max_len - S)))
-            logits, hybrid, word = chunked(
-                params, hybrid, jnp.asarray(row, jnp.int32), jnp.int32(slot),
-                padded, jnp.int32(S), jnp.int32(start_pos))
-            return logits, hybrid, word
-
-        return prefill
 
     if not fused:
         step = jax.jit(step_fn)

@@ -23,8 +23,7 @@ request landed on after a ULFM shrink. This module adds that substrate:
   response, plus first-token instants), ``sched`` (slot assignment,
   requeues), ``window`` (dispatch → retire of one decode window), ``phase``
   (the host loop's own time, see below), ``prefill`` (chunks fed into fused
-  windows, blocking prefills), ``page`` (paged-KV allocate / evict /
-  reclaim), ``spec`` (draft/verify accept–reject per window), ``fault``
+  windows), ``page`` (paged-KV allocate / evict / reclaim), ``spec`` (draft/verify accept–reject per window), ``fault``
   (the error-word history mapped back onto host time: one event per faulted
   ``(step, slot)`` with the exact :class:`~repro.core.errors.ErrorCode`
   word from ``DeviceFuture.fault_codes()``), ``recovery`` (LFLR lane begin

@@ -6,15 +6,17 @@ exceptions* at a wait, never deadlocks or aborts — to inference traffic.
 
 * :class:`RequestQueue` / :class:`AdmissionPolicy` — deadline-aware (EDF)
   admission; every accepted request gets a terminal :class:`Response`.
-* :class:`ContinuousBatchingScheduler` — fixed decode slots, per-step evict +
-  backfill over :func:`repro.launch.steps.make_slot_decode_step`.
-* :class:`Replica` — wraps every fused step in a ``DeviceFuture``; per-slot
-  error words + the paper's enumeration give ``(slot, code)`` attribution, so
-  ``STATE_FAULT`` triggers per-sequence LFLR re-prefill (recompute, don't
-  restart) and a :class:`~repro.core.recovery.RecoveryPolicy` escalates. With
-  ``window=K`` the hot path is the zero-sync decode window; with ``overlap``
-  (default) admission and LFLR ride the windows as background chunked-prefill
-  lanes — the token stream never stalls on a blocking prefill (DESIGN §3.2).
+* :class:`ContinuousBatchingScheduler` — fixed decode slots, evict +
+  backfill at window boundaries, prompt chunks planned into each window.
+* :class:`Replica` — one engine, the overlapped decode window: K greedy steps
+  per dispatch behind a ``DeviceFuture``; per-slot error words + the paper's
+  enumeration give ``(step, slot, code)`` attribution, so ``STATE_FAULT``
+  triggers per-sequence LFLR re-prefill (recompute, don't restart) and a
+  :class:`~repro.core.recovery.RecoveryPolicy` escalates. Admission and LFLR
+  ride the windows as background chunked-prefill lanes — the token stream
+  never stalls on a prefill (DESIGN §3.2).
+* :func:`build_engine` — the one place the window program (plain, paged,
+  tensor-parallel or speculative) is built from an :class:`EngineConfig`.
 * :class:`ServeGroup` — N replicas over the thread-rank transport; a killed
   replica raises on the survivors via the ULFM protocol, the group shrinks and
   re-routes its in-flight requests.
@@ -33,6 +35,7 @@ exceptions* at a wait, never deadlocks or aborts — to inference traffic.
   ``trace_event`` JSON (DESIGN §3.5).
 """
 from .config import EngineConfig  # noqa: F401
+from .engine import EnginePrograms, build_engine  # noqa: F401
 from .group import (  # noqa: F401
     AgreeDecision,
     GroupResult,

@@ -1,32 +1,27 @@
-"""EngineConfig — the one validated construction surface for serving engines.
+"""EngineConfig — the one validated construction surface for the serving engine.
 
-PRs 1–8 grew the engine surface one keyword at a time: ``window=``,
-``overlap=``, ``paged=``/``page_size=``/``page_budget=``/``page_watermark=``,
-``speculate=``/``draft_len=``/``draft_layers=``, ``trace=`` — threaded in
-parallel through :class:`~repro.serve.replica.Replica`,
-:class:`~repro.serve.group.ServeGroup` and the benchmark cells, with the
-cross-field rules (speculation needs windows, paging needs windows, …)
-re-checked ad hoc at each layer. Adding tensor parallelism (``tp=``) would
-have been the eleventh copy of the sprawl, so this dataclass collapses it:
+There is one plain serving engine: the overlapped decode window
+(:func:`~repro.launch.steps.make_prefill_decode_window`) — K greedy steps
+per dispatch, with admission and LFLR recovery fed as prompt chunks inside
+the same scan. ``paged``, ``speculate`` and ``tp`` are variants of that
+engine, built by :func:`~repro.serve.engine.build_engine`.
 
 * every *engine-shape* knob lives here, validated once in ``__post_init__``
-  (cross-field rules included — a bad combination fails at construction, in
-  one place, with one message);
-* :meth:`from_flags` subsumes the ``"win=8,spec=1,dlen=3"``-style string
-  parsing that benchmarks/CLI entry points used to hand-roll per tool;
+  (a bad value fails at construction, in one place, with one message);
+* :meth:`from_flags` parses ``"win=8,spec=1,dlen=3"``-style strings for the
+  CLI and benchmark entry points;
 * ``Replica(...)``/``ServeGroup(...)`` take ``config=EngineConfig(...)`` —
-  the sole construction path. (The PR-9 one-release legacy-kwargs shim has
-  been removed; old shape keywords are plain ``TypeError``\\ s now.)
+  the sole construction path.
 
-Runtime *wiring* (queues, tracers, shared jitted fns, clocks, injectors)
+Runtime *wiring* (queues, tracers, shared programs, clocks, injectors)
 deliberately stays out: those are per-instance objects, not engine shape, and
 an EngineConfig must stay hashable/serialisable so benchmark cells and fuzz
 engine kits can be declared as data.
 
 Model-dependent checks (``speculate`` requires
 ``Model.supports_speculation()``; ``tp`` requires enough devices for the
-"model" mesh axis) stay in the Replica, which is the first layer that has the
-model/devices in hand — but they are *reached* through exactly one path now.
+"model" mesh axis) are made by ``build_engine``, the first place that has
+the model and devices in hand.
 """
 from __future__ import annotations
 
@@ -43,18 +38,19 @@ class EngineConfig:
     sharded by :mod:`repro.sharding.rules`, compute replicated after an
     in-program all-gather — DESIGN §3.8), with per-shard error words
     OR-folded across the axis so a fault on any shard latches identically on
-    all shards. Requires window mode with overlapped admission (the blocking
-    prefill path is not built for TP) and ``tp`` visible devices at
-    construction.
+    all shards. Requires ``tp`` visible devices at construction.
+
+    ``overlap`` accepts only ``True``: the blocking-prefill engine was
+    removed, and the field stays only because benchmark configurations
+    still name it.
     """
 
     num_slots: int = 4
     max_len: int = 64
     eos_id: Optional[int] = None
     max_request_retries: int = 2
-    # ---- decode windows (PR 2/3) --------------------------------------
-    window: int = 0
-    donate: bool = True
+    # ---- decode windows ------------------------------------------------
+    window: int = 4
     overlap: bool = True
     prefill_budget: Optional[int] = None
     # ---- paged KV pool (PR 4) -----------------------------------------
@@ -81,8 +77,14 @@ class EngineConfig:
         if self.max_request_retries < 0:
             raise ValueError("max_request_retries must be >= 0, got "
                              f"{self.max_request_retries}")
-        if self.window < 0:
-            raise ValueError(f"window must be >= 0, got {self.window}")
+        if self.window < 1:
+            raise ValueError(
+                f"window must be >= 1, got {self.window} (the stepwise "
+                "engine was removed: every engine decodes in windows)")
+        if not self.overlap:
+            raise ValueError(
+                "overlap must be True: the blocking-prefill window engine "
+                "was removed (admission and recovery ride the window)")
         if self.prefill_budget is not None and self.prefill_budget < 1:
             raise ValueError("prefill_budget must be >= 1 (or None), got "
                              f"{self.prefill_budget}")
@@ -102,47 +104,28 @@ class EngineConfig:
         if not 0.0 <= self.trace_sample <= 1.0:
             raise ValueError("trace_sample must be in [0, 1], got "
                              f"{self.trace_sample}")
-        # cross-field rules — previously scattered through Replica.__init__
-        if self.paged and not self.window:
-            raise ValueError("paged=True requires window mode (window=K)")
-        if self.speculate and not self.window:
-            raise ValueError("speculate=True requires window mode (window=K)")
-        if self.speculate and not self.overlap:
-            raise ValueError(
-                "speculate=True requires overlap=True (admission/LFLR must "
-                "ride the window: the blocking-prefill patch path assumes a "
-                "host-predictable position chain)")
         if self.tp < 1:
             raise ValueError(f"tp must be >= 1, got {self.tp}")
-        if self.tp > 1 and not self.window:
-            raise ValueError(
-                "tp>1 requires window mode (window=K): the cross-shard "
-                "error-word fold lives in the window enumeration")
-        if self.tp > 1 and not self.overlap:
-            raise ValueError(
-                "tp>1 requires overlap=True: admission/LFLR must ride the "
-                "sharded windows (the blocking prefill path is single-device)")
 
     # ------------------------------------------------------------ construction
     @classmethod
     def from_flags(cls, spec: str, **overrides) -> "EngineConfig":
         """Parse ``"win=8,spec=1,dlen=3,tp=2,paged=1,page=16"`` → EngineConfig.
 
-        One parser for every CLI/benchmark entry point (subsumes the
-        ``spec/dlen/dlayers`` string parsing the tools used to duplicate).
-        Bare keys are boolean shorthand (``"paged,spec"`` ≡
-        ``"paged=1,spec=1"``); ``overrides`` are applied on top (a tool's
-        fixed ``num_slots`` beats the flag string). Unknown keys raise — a
-        typo must not silently configure the default engine.
+        One parser for every CLI/benchmark entry point. Bare keys are
+        boolean shorthand (``"paged,spec"`` ≡ ``"paged=1,spec=1"``);
+        ``overrides`` are applied on top (a tool's fixed ``num_slots`` beats
+        the flag string). Unknown keys raise — a typo must not silently
+        configure the default engine.
         """
-        bool_fields = {"donate", "overlap", "paged", "speculate", "trace"}
+        bool_fields = {"overlap", "paged", "speculate", "trace"}
         alias = {
             "win": "window", "window": "window",
             "slots": "num_slots", "num_slots": "num_slots",
             "max_len": "max_len", "eos": "eos_id", "eos_id": "eos_id",
             "retries": "max_request_retries",
             "max_request_retries": "max_request_retries",
-            "donate": "donate", "overlap": "overlap",
+            "overlap": "overlap",
             "budget": "prefill_budget", "prefill_budget": "prefill_budget",
             "page": "page_size", "page_size": "page_size",
             "paged": "paged", "pages": "page_budget",
@@ -165,9 +148,9 @@ class EngineConfig:
                     f"{sorted(set(alias))})")
             field = alias[k]
             if not v:
-                if field not in bool_fields and field != "window":
+                if field not in bool_fields:
                     raise ValueError(f"engine flag {k!r} needs a value")
-                kw[field] = True if field in bool_fields else kw.get(field, 0)
+                kw[field] = True
                 continue
             if field in bool_fields:
                 kw[field] = bool(int(v))

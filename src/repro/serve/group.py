@@ -68,21 +68,15 @@ import jax
 from ..core import CommCorruptedError, PropagatedError, initialize, run_ranks
 from ..core.faults import FaultSchedule
 from ..core.transport import RankResult
-from ..launch.steps import (
-    make_cache_prefill,
-    make_decode_window,
-    make_prefill_decode_window,
-    make_slot_decode_step,
-    make_speculative_decode_window,
-)
 from ..models import build_model
 from ..obs.trace import NULL_TRACER, Tracer, merge_traces
 from .config import EngineConfig
+from .engine import SERVE_PROBES, build_engine
 from .ledger import GroupLedger, WriteAheadLog
 from .ledger import replay as replay_ledger
 from .metrics import ServeMetrics
 from .queue import AdmissionPolicy, Request, RequestQueue, Response
-from .replica import SERVE_PROBES, Replica
+from .replica import Replica
 
 # chunking of the simulated join-time state transfer: enough chunks (with a
 # short host pause each) that the join window spans several decode rounds —
@@ -242,22 +236,10 @@ class ServeGroup:
         self.num_slots = config.num_slots
         self.max_len = config.max_len
         self.timeout = timeout
-        self.max_request_retries = config.max_request_retries
-        self.eos_id = config.eos_id
-        self.window = int(config.window)
-        self.overlap = bool(self.window) and bool(config.overlap)
-        self.prefill_budget = config.prefill_budget
         self.paged = bool(config.paged)
-        self.page_size = config.page_size
-        self.page_budget = config.page_budget
-        self.page_watermark = config.page_watermark
-        self.speculate = bool(config.speculate)
-        self.draft_len = int(config.draft_len)
-        self.draft_layers = int(config.draft_layers)
         self.tp = int(config.tp)
         self.trace = bool(config.trace)
         self.trace_sample = float(config.trace_sample)
-        donate = config.donate
         self.params = build_model(cfg).init(jax.random.PRNGKey(seed))
         # one chip per rank where there are enough: rank r serves from
         # jax.devices()[r % n], with its own copy of the params (a tp>1 rank
@@ -268,74 +250,12 @@ class ServeGroup:
         self._rank_params = ({} if self.tp > 1 else
                              {d: jax.device_put(self.params, d)
                               for d in dict.fromkeys(self._devices)})
-        # compile once, share across rank threads (jit dispatch is thread-safe)
-        # — each paged replica owns its own pool + table, but the layout (and
-        # therefore every jitted program) is identical across the fleet
-        if self.paged:
-            from ..launch.paging import PagedLayout
-            model = build_model(cfg)
-            num_pages = (int(self.page_budget) if self.page_budget is not None
-                         else self.num_slots * (self.max_len // self.page_size))
-            self._layout = PagedLayout(model.init_cache(1, self.max_len),
-                                       self.max_len,
-                                       page_size=self.page_size,
-                                       num_pages=num_pages)
-        else:
-            self._layout = None
-        # tensor-parallel fleet: ONE TPContext (mesh + storage specs) shared
-        # by the jitted window program below and by every rank's Replica —
-        # jax.make_mesh with identical args yields equal Mesh objects, so the
-        # per-rank replicas hit the same compilation cache
-        self._tp_ctx = None
-        if self.tp > 1:
-            self._tp_ctx = self._make_tp_ctx()
-        self._decode_fn = jax.jit(make_slot_decode_step(cfg, probe_cfg))
-        self._prefill_fn = make_cache_prefill(cfg, probe_cfg,
-                                              fused=bool(self.window),
-                                              paged=self._layout,
-                                              donate=bool(self.paged and donate))
-        if not self.window:
-            self._window_fn = None
-        elif self.speculate:
-            self._window_fn = make_speculative_decode_window(
-                cfg, probe_cfg, window=self.window, draft_len=self.draft_len,
-                draft_layers=self.draft_layers, donate=donate,
-                paged=self._layout, tp=self._tp_ctx)
-        elif self.overlap:
-            self._window_fn = make_prefill_decode_window(
-                cfg, probe_cfg, window=self.window, donate=donate,
-                paged=self._layout, tp=self._tp_ctx)
-        else:
-            self._window_fn = make_decode_window(
-                cfg, probe_cfg, window=self.window, donate=donate,
-                paged=self._layout, tp=self._tp_ctx)
-
-    def _make_tp_ctx(self):
-        """The fleet-shared :class:`~repro.launch.steps.TPContext`: same mesh
-        and storage specs every rank's Replica derives for itself, computed
-        once here so the shared window program is sharded at build time.
-        Cache specs come from shape templates only — nothing is materialised."""
-        from ..launch.steps import TPContext
-        from ..sharding.rules import param_specs, tp_storage_specs
-        ndev = len(jax.devices())
-        if ndev < self.tp:
-            raise ValueError(
-                f"tp={self.tp} requires {self.tp} devices, found {ndev} "
-                "(on CPU, force host devices with XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={self.tp})")
-        mesh = jax.make_mesh((self.tp,), ("model",))
-        one = build_model(self.cfg).init_cache(1, self.max_len)
-        if self.paged:
-            hybrid = self._layout.init_hybrid(one, self.num_slots)
-            cspecs = self._layout.tp_storage_specs(hybrid, mesh)
-        else:
-            stacked = jax.tree_util.tree_map(
-                lambda v: jax.ShapeDtypeStruct((self.num_slots, *v.shape),
-                                               v.dtype), one)
-            cspecs = tp_storage_specs(stacked, mesh)
-        return TPContext(mesh=mesh,
-                         param_specs=param_specs(self.params, mesh),
-                         cache_specs=cspecs)
+        # compile once, share across rank threads (jit dispatch is
+        # thread-safe): every rank's Replica gets the same window program,
+        # paged layout and TP context — each paged replica owns its own pool
+        # and table, but the programs addressing them are identical
+        self._programs = build_engine(cfg, self.params, config, probe_cfg)
+        self._layout = self._programs.layout
 
     def device_of(self, rank: int):
         """The device rank ``rank``'s replica serves from (None when tp>1:
@@ -464,9 +384,7 @@ class ServeGroup:
                 self.cfg, params=(self.params if self.tp > 1 else
                                   self._rank_params[self._devices[rank]]),
                 config=self.config,
-                queue=queue, rank=rank,
-                decode_fn=self._decode_fn, prefill_fn=self._prefill_fn,
-                window_fn=self._window_fn, paged_layout=self._layout)
+                queue=queue, rank=rank, programs=self._programs)
 
         def serve_rounds(ctx, comm, replica, tracer, report, my_epoch, *,
                          inject_faults=True):

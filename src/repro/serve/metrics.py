@@ -39,17 +39,14 @@ class ServeMetrics:
                                              # lives here, index-aligned)
         self.faults: list[FaultRecord] = []
         self.decode_steps = 0
-        self.prefills = 0
-        self.decode_tokens = 0               # all committed tokens (incl. the
-        self._t0: Optional[float] = None     # first one, from prefill logits)
+        self.decode_tokens = 0               # all committed tokens
+        self._t0: Optional[float] = None
         self._t_last: Optional[float] = None
         self.windows = 0                     # decode windows retired
         self.discarded_tokens = 0            # trailing tokens dropped at window
                                              # boundaries (EOS/budget/fault)
         self.prefill_chunks = 0              # prompt chunks fused into windows
         self.prefill_chunk_tokens = 0        # prompt tokens fed via chunks
-        self.host_stalls = 0                 # blocking prefills (admission/LFLR
-        self.host_stall_s = 0.0              # that froze the dispatch loop)
         self.window_waits = 0                # windows not yet done at retire
                                              # (device-bound, host keeping up)
         self.pages_allocated = 0             # paged KV: pool pages granted
@@ -67,12 +64,6 @@ class ServeMetrics:
         self._spec_per_slot: dict[int, list] = {}   # slot -> [drafted, accepted]
 
     # ------------------------------------------------------------- recording
-    def record_step(self, committed_tokens: int) -> None:
-        with self._lock:
-            self._tick()
-            self.decode_steps += 1
-            self.decode_tokens += committed_tokens
-
     def record_window(self, committed_tokens: int, discarded_tokens: int,
                       window: int) -> None:
         """One retired decode window: K deferred device steps, one host sync."""
@@ -83,26 +74,12 @@ class ServeMetrics:
             self.decode_tokens += committed_tokens
             self.discarded_tokens += discarded_tokens
 
-    def record_prefill(self, committed_tokens: int = 1) -> None:
-        """A (re-)prefill that committed its first token from prefill logits."""
-        with self._lock:
-            self._tick()
-            self.prefills += 1
-            self.decode_tokens += committed_tokens
-
     def record_chunk(self, tokens_fed: int) -> None:
         """A prompt chunk fused into a decode window (overlapped prefill)."""
         with self._lock:
             self._tick()
             self.prefill_chunks += 1
             self.prefill_chunk_tokens += tokens_fed
-
-    def record_host_stall(self, seconds: float) -> None:
-        """Wall time the dispatch loop spent blocked on a synchronous prefill
-        — the stall the overlapped engine exists to eliminate."""
-        with self._lock:
-            self.host_stalls += 1
-            self.host_stall_s += max(0.0, seconds)
 
     def record_window_wait(self) -> None:
         """A window that was still computing when the host came to retire it."""
@@ -233,14 +210,11 @@ class ServeMetrics:
             "requests": len(self.responses),
             "statuses": self.by_status(),
             "decode_steps": self.decode_steps,
-            "prefills": self.prefills,
             "decode_tokens": self.decode_tokens,
             "windows": self.windows,
             "discarded_tokens": self.discarded_tokens,
             "prefill_chunks": self.prefill_chunks,
             "prefill_chunk_tokens": self.prefill_chunk_tokens,
-            "host_stalls": self.host_stalls,
-            "host_stall_s": self.host_stall_s,
             "window_waits": self.window_waits,
             "pages_allocated": self.pages_allocated,
             "pages_freed": self.pages_freed,
@@ -308,14 +282,11 @@ class ServeMetrics:
                 out._resp_t.extend(m._resp_t)
                 out.faults.extend(m.faults)
                 out.decode_steps += m.decode_steps
-                out.prefills += m.prefills
                 out.decode_tokens += m.decode_tokens
                 out.windows += m.windows
                 out.discarded_tokens += m.discarded_tokens
                 out.prefill_chunks += m.prefill_chunks
                 out.prefill_chunk_tokens += m.prefill_chunk_tokens
-                out.host_stalls += m.host_stalls
-                out.host_stall_s += m.host_stall_s
                 out.window_waits += m.window_waits
                 out.pages_allocated += m.pages_allocated
                 out.pages_freed += m.pages_freed

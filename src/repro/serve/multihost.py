@@ -503,7 +503,10 @@ class MultiHostSupervisor:
 
         def reader(sock: socket.socket) -> None:
             """Per-connection reader: the first frame must be ``hello`` (it
-            names the rank); afterwards every frame lands in the inbox."""
+            names the rank); afterwards every frame lands in the inbox,
+            stamped with its arrival time — the detector measures a host's
+            silence from when its beat arrived, not from when the serve
+            loop got round to it."""
             try:
                 first = recv_msg(sock)
             except OSError:
@@ -516,16 +519,16 @@ class MultiHostSupervisor:
                 return
             rank = int(first["rank"])
             conns[rank] = _Conn(sock)
-            inbox.put((rank, first))
+            inbox.put((rank, first, time.monotonic()))
             while True:
                 try:
                     msg = recv_msg(sock)
                 except OSError:
                     msg = None
                 if msg is None:
-                    inbox.put((rank, {"type": "_eof"}))
+                    inbox.put((rank, {"type": "_eof"}, time.monotonic()))
                     return
-                inbox.put((rank, msg))
+                inbox.put((rank, msg, time.monotonic()))
 
         def acceptor() -> None:
             while not stop_accept.is_set():
@@ -646,12 +649,14 @@ class MultiHostSupervisor:
                     t.start()
                     timers.append(t)
 
-        def handle(rank: int, msg: dict, now: float) -> None:
+        def handle(rank: int, msg: dict, now: float,
+                   arrived: Optional[float] = None) -> None:
             nonlocal retired_total
             kind = msg.get("type")
+            t = now if arrived is None else arrived
             if kind == "hello":
                 ready.add(rank)
-                detector.register(rank, now)
+                detector.register(rank, t)
                 reqs = ledger.take(rank)
                 conns[rank].send({
                     "type": "work", "rerouted": False,
@@ -660,10 +665,10 @@ class MultiHostSupervisor:
             elif kind == "hb":
                 if rank not in live:
                     return
-                b = beats.setdefault(rank, [now, now, 0])
-                b[1] = now
+                b = beats.setdefault(rank, [t, t, 0])
+                b[1] = t
                 b[2] += 1
-                if detector.heartbeat(rank, now):
+                if detector.heartbeat(rank, t):
                     resumed.add(rank)
                     if tracer.enabled:
                         tracer.instant("host_suspect_clear", "host", ts=now,
@@ -719,13 +724,20 @@ class MultiHostSupervisor:
                     failure = (f"all workers finished but "
                                f"{ledger.remaining()} requests unanswered")
                     break
+                # drain everything that has arrived before the detector
+                # judges silence: a beat waiting in the inbox is not a miss
                 try:
-                    rank, msg = inbox.get(timeout=0.01)
+                    batch = [inbox.get(timeout=0.01)]
                 except queue.Empty:
-                    rank, msg = -1, None
+                    batch = []
+                while True:
+                    try:
+                        batch.append(inbox.get_nowait())
+                    except queue.Empty:
+                        break
+                for rank, msg, arrived in batch:
+                    handle(rank, msg, time.monotonic(), arrived)
                 now = time.monotonic()
-                if msg is not None:
-                    handle(rank, msg, now)
                 newly, evictable = detector.poll(now)
                 for r in newly:
                     if r in live:
@@ -748,7 +760,7 @@ class MultiHostSupervisor:
             drain_until = time.monotonic() + 2.0
             while time.monotonic() < drain_until:
                 try:
-                    rank, msg = inbox.get(timeout=0.05)
+                    rank, msg, _ = inbox.get(timeout=0.05)
                 except queue.Empty:
                     if all(p.poll() is not None for p in procs.values()):
                         break

@@ -1,38 +1,34 @@
-"""Serving replica: fused slot-decode behind a DeviceFuture, per-sequence LFLR.
+"""Serving replica: the overlapped decode window, per-sequence LFLR.
 
-One replica owns a fixed-slot continuous batch over
-:func:`~repro.launch.steps.make_slot_decode_step`. Every dispatched step is
-wrapped in a :class:`~repro.core.device_channel.DeviceFuture`; the per-slot
-error words run through the paper's enumeration algorithm so the
-``PropagatedError`` raised at the wait carries exact ``(slot, code)`` pairs.
+One replica owns a fixed-slot continuous batch served by one engine: the
+**overlapped decode window**
+(:func:`~repro.launch.steps.make_prefill_decode_window`, built by
+:func:`~repro.serve.engine.build_engine`). K greedy steps run fully on
+device per dispatch; fault detection is deferred to the window boundary (the
+paper's asynchrony contract — errors latch in-band, raise at the *wait*);
+the per-slot ``(K, slots)`` error-word history runs through the paper's
+enumeration algorithm, so the ``PropagatedError`` raised at the wait carries
+exact ``(slot, code)`` pairs and the history names the exact step.
 
-With ``window=K`` the hot path moves to the **zero-sync decode window**
-(:func:`~repro.launch.steps.make_decode_window`): K greedy steps run fully on
-device, fault detection is deferred to the window boundary (the paper's
-asynchrony contract — errors latch in-band, raise at the *wait*), and the
-commit loop is **double-buffered**: window N+1 is dispatched from window N's
-device-resident outputs (next token + donated caches) *before* window N's
-token block is read back, so the device never idles on a host round trip.
-Host syncs scale with ``steps / K`` instead of ``steps``. EOS / deadline /
-faulted slots are handled at window boundaries: trailing tokens are
-discarded, freed lanes are backfilled, and the already-in-flight speculative
-window is patched — its stale lanes are marked invalid and simply skipped at
-its own retirement.
+The commit loop is **double-buffered**: window N+1 is dispatched from window
+N's device-resident outputs (next token + donated caches) *before* window
+N's token block is read back, so the device never idles on a host round
+trip. Host syncs scale with ``steps / K``. EOS / deadline / faulted slots
+are handled at window boundaries: trailing tokens are discarded, freed lanes
+are backfilled, and the already-in-flight window is patched — its stale
+lanes are marked invalid and simply skipped at its own retirement.
 
-With ``overlap=True`` (the default in window mode) admission and LFLR
-recovery become **background prefill lanes** driven by the scheduler: instead
-of a blocking full-length prefill between windows, a joining or recovering
-slot's pending sequence is chunked into the *fused* decode+prefill window
-(:func:`~repro.launch.steps.make_prefill_decode_window`) — the token stream
-of the healthy slots never stalls, and the lane flips to decoding inside the
-window whose chunk consumes its last pending token (bit-exact vs the blocking
-path, since both compute the first token as the argmax after the last prompt
-token through the same decode step). A fault latched during a chunk is
-attributed through the same ``(K, slots)`` history and re-queues the lane
-(cache reset + chunk from position 0) without a single host sync.
+Admission and LFLR recovery are **background prefill lanes** driven by the
+scheduler: a joining or recovering slot's pending sequence is chunked into
+the window scan, so the token stream of the healthy slots never stalls, and
+the lane flips to decoding inside the window whose chunk consumes its last
+pending token (the argmax after the last prompt token is the first
+generated token). A fault latched during a chunk is attributed through the
+same ``(K, slots)`` history and re-queues the lane (cache reset + chunk
+from position 0) without a single host sync.
 
-With ``speculate=True`` (window + overlap mode, full-attention archs) the
-window becomes a **speculative decode window**
+With ``speculate=True`` (full-attention archs) the window becomes a
+**speculative decode window**
 (:func:`~repro.launch.steps.make_speculative_decode_window`): every window
 step drafts ``draft_len`` tokens with a shallow-exit self-draft and verifies
 them in one batched full-model forward, emitting 1..D+1 tokens per step —
@@ -73,25 +69,14 @@ from ..core.device_channel import WORD_DTYPE, DeviceFuture
 from ..core.errors import ErrorCode, PropagatedError
 from ..core.faults import INJECTABLE_CODE_MASK as _INJECTABLE_MASK
 from ..core.recovery import Action, RecoveryPolicy
-from ..launch.paging import PagedLayout
-from ..launch.steps import (
-    TPContext,
-    make_cache_prefill,
-    make_decode_window,
-    make_prefill_decode_window,
-    make_slot_decode_step,
-    make_speculative_decode_window,
-)
+from ..launch.steps import TPContext
 from ..models import build_model
 from ..obs.trace import NULL_TRACER, SHARD_TID, Tracer
 from .config import EngineConfig
+from .engine import SERVE_PROBES, EnginePrograms, build_engine
 from .metrics import ServeMetrics
 from .queue import EXPIRED, FAILED, AdmissionPolicy, Request, RequestQueue, Response
 from .scheduler import ContinuousBatchingScheduler, PageAllocator, PagePoolExhausted
-
-# CPU/interpret backends fall back to the fused-by-XLA probe oracle anyway;
-# forcing it keeps the vmapped step portable (see kernels/fault_probe/ops.py).
-SERVE_PROBES = ProbeConfig(use_kernel=False)
 
 
 def _home_device(tree):
@@ -126,12 +111,10 @@ def make_enum_fn(num_slots: int):
 def make_window_enum_fn(num_slots: int, ignore: int = 0):
     """Jitted ``(history (K, S), mask (S,)) -> (combined, count, table, hist)``.
 
-    The window variant of :func:`make_enum_fn`: free slots are masked out of
-    the whole ``(K, slots)`` word history, per-slot words are OR-folded over
-    the window (deferred detection — one check per K tokens), and the fold is
-    handed to the *same* per-slot enumeration the stepwise engine uses, so
-    the two engines cannot diverge in attribution semantics. The masked
-    history rides along so :meth:`DeviceFuture.fault_steps` can attribute a
+    Free slots are masked out of the whole ``(K, slots)`` word history,
+    per-slot words are OR-folded over the window (deferred detection — one
+    check per K tokens), and the fold is handed to the per-slot enumeration
+    of :func:`make_enum_fn`. The masked history rides along so :meth:`DeviceFuture.fault_steps` can attribute a
     fault to its exact ``(step, slot)`` on the (rare) fault path only.
 
     ``ignore`` strips attribution-only code bits (``DRAFT_REJECT``) from the
@@ -211,25 +194,16 @@ class Replica:
                  probe_cfg: ProbeConfig = SERVE_PROBES,
                  rank: int = 0, seed: int = 0,
                  clock: Callable[[], float] = time.monotonic,
-                 decode_fn: Callable | None = None,
-                 prefill_fn: Callable | None = None,
-                 window_fn: Callable | None = None,
-                 paged_layout: Optional[PagedLayout] = None,
+                 programs: Optional[EnginePrograms] = None,
                  tracer: Optional[Tracer] = None,
                  fault_injector: Optional[Callable] = None,
                  page_debug: Optional[bool] = None):
         # engine *shape* lives in one validated EngineConfig; runtime wiring
-        # (queue, policy, shared jitted fns, tracer, injector, clock) stays as
+        # (queue, policy, shared programs, tracer, injector, clock) stays as
         # real keywords.
         config = config if config is not None else EngineConfig()
         self.config = config
         num_slots, max_len = config.num_slots, config.max_len
-        window, donate, overlap = config.window, config.donate, config.overlap
-        prefill_budget, eos_id = config.prefill_budget, config.eos_id
-        paged, page_size = config.paged, config.page_size
-        page_budget, page_watermark = config.page_budget, config.page_watermark
-        speculate = config.speculate
-        draft_len, draft_layers = config.draft_len, config.draft_layers
         self.cfg = cfg
         self.model = build_model(cfg)
         self.params = params if params is not None else self.model.init(
@@ -257,7 +231,7 @@ class Replica:
         self.max_request_retries = config.max_request_retries
         # deterministic in-band fault-word injection (the fuzzer's device
         # mutation surface): called once per dispatch with the dispatch index
-        # and the words shape — (slots,) stepwise, (K, slots) windowed — and
+        # and the words shape — (K, slots), or (tp, K, slots) sharded — and
         # may return a uint32 array OR'd into the device error words *before*
         # enumeration, so injected codes ride the exact deferred-detection /
         # attribution path a probe-latched fault would. None = no injection.
@@ -267,34 +241,27 @@ class Replica:
         # site so ledger corruption surfaces at the mutation site instead of
         # steps later. Defaults to __debug__ (off under python -O).
         self._page_debug = bool(__debug__ if page_debug is None else page_debug)
-        self.window = int(window)
-        self.overlap = bool(self.window) and bool(overlap)
+        self.window = int(config.window)
         # ---- speculative decode windows (speculate=True) ------------------
         # draft-and-verify inside the fused window: up to draft_len+1 tokens
         # per full-model step, token-bit-exact vs the plain engine; the
         # commit loop consumes a per-(step, slot) accepted-count readback
         # instead of assuming K tokens per window (DESIGN.md §3.4)
-        self.speculate = bool(speculate)
-        self.draft_len = int(draft_len)
-        self.draft_layers = int(draft_layers)
-        if self.speculate:
-            if not self.window:
-                raise ValueError("speculate=True requires window mode "
-                                 "(window=K)")
-            if not self.overlap:
-                raise ValueError("speculate=True requires overlap=True "
-                                 "(admission/LFLR must ride the window: the "
-                                 "blocking-prefill patch path assumes a "
-                                 "host-predictable position chain)")
-            if not self.model.supports_speculation():
-                raise ValueError(
-                    f"{cfg.name}: speculation requires a pure full-attention"
-                    ", non-MoE architecture")
-        # ---- paged KV/state pool (paged=True, window mode only) -----------
+        self.speculate = bool(config.speculate)
+        self.draft_len = int(config.draft_len)
+        self.draft_layers = int(config.draft_layers)
+        # the window program and the layout it was built for: handed in by a
+        # ServeGroup / fuzz kit (compiled once for many replicas), else
+        # built here
+        if programs is None:
+            programs = build_engine(cfg, self.params, config, probe_cfg)
+        self._decode_window = programs.window_fn
+        # ---- paged KV/state pool (paged=True) ------------------------------
         # full-attention caches become one shared page pool addressed through
         # a (slots, max_pages) table; the allocator owns the free list and
         # the per-slot ownership ledger (DESIGN.md §3.3)
-        self.paged = bool(paged)
+        self.paged = bool(config.paged)
+        self.layout = programs.layout
         # a single-device replica lives where its params live: its caches go
         # to that device too (a ServeGroup puts each rank on its own chip)
         self.device = _home_device(self.params) if config.tp == 1 else None
@@ -302,39 +269,13 @@ class Replica:
         if self.device is not None:
             one = jax.device_put(one, self.device)
         if self.paged:
-            if not self.window:
-                raise ValueError("paged=True requires window mode (window=K)")
-            num_pages = (int(page_budget) if page_budget is not None
-                         else num_slots * (max_len // page_size))
-            self.layout = paged_layout or PagedLayout(
-                one, max_len, page_size=page_size, num_pages=num_pages)
             self.alloc = PageAllocator(self.layout.num_pages,
                                        self.layout.page_size,
-                                       watermark=page_watermark)
+                                       watermark=config.page_watermark)
             self.page_table = self.layout.empty_table(num_slots)
             self._scrub = jax.jit(self.layout.scrub, donate_argnums=(0,))
         else:
-            self.layout = None
             self.alloc = None
-        # jitted step functions are shareable across replicas (ServeGroup
-        # builds them once so N rank threads compile once, not N times)
-        self._decode = decode_fn or jax.jit(
-            make_slot_decode_step(cfg, probe_cfg))
-        self._prefill = prefill_fn or make_cache_prefill(
-            cfg, probe_cfg, fused=bool(window),
-            paged=self.layout if self.paged else None,
-            donate=bool(self.paged and donate))
-        self._enum = make_enum_fn(num_slots)
-        # fused one-dispatch insertion of a rebuilt per-sequence cache into the
-        # slot-stacked caches (the un-jitted tree_map was one dispatch per
-        # leaf); the window-mode device token feed rides in the same dispatch
-        self._insert = jax.jit(
-            lambda full, one, slot, dev_toks, tok: (
-                jax.tree_util.tree_map(
-                    lambda f, o: f.at[slot].set(o.astype(f.dtype)), full, one),
-                dev_toks.at[slot, 0, 0].set(tok)))
-        self._set_tok = jax.jit(
-            lambda dev_toks, slot, tok: dev_toks.at[slot, 0, 0].set(tok))
         if self.paged and self.layout.has_paged_leaves:
             # a request that could never fit in the pool must be REJECTED at
             # submit, not deferred forever by the watermark gate
@@ -346,8 +287,8 @@ class Replica:
             AdmissionPolicy(max_total_len=pool_cap), clock=clock,
             tracer=self.trace)
         self.sched = ContinuousBatchingScheduler(
-            num_slots, self.queue, replica=rank, eos_id=eos_id, clock=clock,
-            prefill_budget=prefill_budget,
+            num_slots, self.queue, replica=rank, eos_id=config.eos_id,
+            clock=clock, prefill_budget=config.prefill_budget,
             can_admit=(self._can_admit if self.paged else None),
             on_release=(self._release_pages if self.paged else None))
         # stacked per-sequence (batch=1) caches, leading slot axis — or, when
@@ -361,76 +302,40 @@ class Replica:
                 one)
         if self.device is not None:
             self.caches = jax.device_put(self.caches, self.device)
-        # ---- tensor parallelism (tp > 1, window + overlap mode) -----------
+        # ---- tensor parallelism (tp > 1) ------------------------------------
         # one replica = tp shards of a "model" mesh: params and cache leaves
         # are STORED sharded (rules.param_specs / tp_storage_specs), compute
         # stays replicated inside the shard_mapped window, and per-shard
         # error words are OR-folded across the axis so a fault on any shard
         # latches identically on all shards (DESIGN §3.8)
         self.tp = int(config.tp)
-        self._tp_ctx: Optional[TPContext] = None
-        if self.tp > 1:
-            ndev = len(jax.devices())
-            if ndev < self.tp:
-                raise ValueError(
-                    f"tp={self.tp} requires {self.tp} devices, found {ndev} "
-                    "(on CPU, force host devices with XLA_FLAGS="
-                    f"--xla_force_host_platform_device_count={self.tp})")
+        self._tp_ctx: Optional[TPContext] = programs.tp
+        if self._tp_ctx is not None:
             from jax.sharding import NamedSharding
-            from ..sharding.rules import param_specs, tp_storage_specs
-            mesh = jax.make_mesh((self.tp,), ("model",))
-            pspecs = param_specs(self.params, mesh)
-            cspecs = (self.layout.tp_storage_specs(self.caches, mesh)
-                      if self.paged else
-                      tp_storage_specs(self.caches, mesh))
-            self._tp_ctx = TPContext(mesh=mesh, param_specs=pspecs,
-                                     cache_specs=cspecs)
-            ns = lambda s: NamedSharding(mesh, s)  # noqa: E731
+            ctx = self._tp_ctx
+            ns = lambda s: NamedSharding(ctx.mesh, s)  # noqa: E731
             self.params = jax.device_put(
-                self.params, jax.tree_util.tree_map(ns, pspecs))
+                self.params, jax.tree_util.tree_map(ns, ctx.param_specs))
             self.caches = jax.device_put(
-                self.caches, jax.tree_util.tree_map(ns, cspecs))
-        self._slot_logits = jnp.zeros((num_slots, 1, 1, cfg.vocab_size),
-                                      jnp.float32)
+                self.caches, jax.tree_util.tree_map(ns, ctx.cache_specs))
         self._step_count = 0
         self._cycles = 0        # step() calls: the serve.step phase's index
-        # ---- zero-sync decode windows (window=K > 0) ----------------------
-        if self.window:
-            if window_fn is not None:
-                self._decode_window = window_fn
-            elif self.speculate:
-                self._decode_window = make_speculative_decode_window(
-                    cfg, probe_cfg, window=self.window,
-                    draft_len=self.draft_len, draft_layers=self.draft_layers,
-                    donate=donate, paged=self.layout if self.paged else None,
-                    tp=self._tp_ctx)
-            elif self.overlap:
-                self._decode_window = make_prefill_decode_window(
-                    cfg, probe_cfg, window=self.window, donate=donate,
-                    paged=self.layout if self.paged else None,
-                    tp=self._tp_ctx)
-            else:
-                self._decode_window = make_decode_window(
-                    cfg, probe_cfg, window=self.window, donate=donate,
-                    paged=self.layout if self.paged else None,
-                    tp=self._tp_ctx)
-            # speculation misses (DRAFT_REJECT) are attribution-only: strip
-            # them from the fault-raising fold so they never reach wait()
-            self._ignore_codes = (int(ErrorCode.DRAFT_REJECT)
-                                  if self.speculate else 0)
-            self._wenum = make_window_enum_fn(num_slots, self._ignore_codes)
-        if self.overlap or self.paged:
-            # fresh per-sequence cache template + fused one-dispatch reset of
-            # one lane's slice of the stacked caches — the overlapped
-            # admission/LFLR restart point (async, never a host sync). In
-            # paged mode the reset covers the dense leaves only; the paged
-            # half of the restart is the page scrub at re-allocation.
-            self._fresh = one
-            reset = (self.layout.reset_slot if self.paged else
-                     lambda full, fresh, slot: jax.tree_util.tree_map(
-                         lambda f, o: f.at[slot].set(o.astype(f.dtype)),
-                         full, fresh))
-            self._reset = jax.jit(reset, donate_argnums=(0,))
+        # speculation misses (DRAFT_REJECT) are attribution-only: strip them
+        # from the fault-raising fold so they never reach wait()
+        self._ignore_codes = (int(ErrorCode.DRAFT_REJECT)
+                              if self.speculate else 0)
+        self._wenum = make_window_enum_fn(num_slots, self._ignore_codes)
+        # fresh per-sequence cache template + fused one-dispatch reset of one
+        # lane's slice of the stacked caches — the admission/LFLR restart
+        # point (async, never a host sync). In paged mode the reset covers
+        # the dense leaves only; the paged half of the restart is the page
+        # scrub at re-allocation.
+        self._fresh = one
+        reset = (self.layout.reset_slot if self.paged else
+                 lambda full, fresh, slot: jax.tree_util.tree_map(
+                     lambda f, o: f.at[slot].set(o.astype(f.dtype)),
+                     full, fresh))
+        self._reset = jax.jit(reset, donate_argnums=(0,))
         self._pending: Optional[_WindowInFlight] = None
         # device-resident feed for the next window (token chain never leaves
         # the device) + host-tracked dispatch positions. With speculation the
@@ -753,9 +658,8 @@ class Replica:
     def _injection_for(self, shape: tuple) -> Optional[np.ndarray]:
         """The injector's validated fault word(s) for this dispatch, or None
         when nothing is scheduled. Shape is the engine's word surface:
-        ``(slots,)`` stepwise, ``(K, slots)`` windowed, ``(tp, K, slots)``
-        tensor-parallel (shard-targeted injection — the TP kit's device
-        mutation surface)."""
+        ``(K, slots)``, or ``(tp, K, slots)`` tensor-parallel (shard-targeted
+        injection — the TP kit's device mutation surface)."""
         if self._injector is None:
             return None
         inj = self._injector(self._step_count, shape)
@@ -792,9 +696,9 @@ class Replica:
         """One scheduler cycle: expire → backfill/prefill → fused decode →
         commit. Returns every request answered during the cycle.
 
-        Traced, the cycle is the ``serve.step`` phase; the window engine's
-        parts of it are the ``serve.admit`` / ``dispatch`` / ``wait`` /
-        ``commit`` / ``recover`` phases (``repro.obs.trace``)."""
+        Traced, the cycle is the ``serve.step`` phase; its parts are the
+        ``serve.admit`` / ``dispatch`` / ``wait`` / ``commit`` / ``recover``
+        phases (``repro.obs.trace``)."""
         self._cycles += 1
         with self.trace.phase("serve.step") as ph:
             if self.trace.enabled:
@@ -804,11 +708,8 @@ class Replica:
                     adm.note(step=self._cycles)
                 out = self._admit()
             self.metrics.record_active_slots(self.sched.in_flight())
-            if self.window:
-                if self.sched.has_active() or self._pending is not None:
-                    out.extend(self._window_cycle())
-            elif self.sched.has_active():
-                out.extend(self._decode_step())
+            if self.sched.has_active() or self._pending is not None:
+                out.extend(self._window_cycle())
             for resp in out:
                 self.metrics.record_response(resp)
             if self.trace.enabled:
@@ -819,8 +720,9 @@ class Replica:
         return out
 
     def _admit(self) -> list[Response]:
-        """Expire what passed its deadline and backfill free slots (a
-        blocking prefill when not overlapped); returns the answers."""
+        """Expire what passed its deadline and backfill free slots; returns
+        the answers. Admission is a background lane: the scheduler chunks
+        each new prompt into the following windows — no blocking prefill."""
         now = self.clock()
         out: list[Response] = []
         for req in self.queue.drain_expired(now):
@@ -834,14 +736,7 @@ class Replica:
             if self.trace.enabled and _req.trace_id is not None:
                 self.trace.instant("slot_assign", "sched", ts=now, tid=slot,
                                    trace_id=_req.trace_id, slot=slot)
-            if self.overlap:
-                # admission is a background lane: the scheduler chunks the
-                # prompt into subsequent decode windows — no blocking prefill
-                self.sched.begin_prefill(slot)
-            else:
-                resp = self._prefill_slot(slot)
-                if resp is not None:
-                    out.append(resp)
+            self.sched.begin_prefill(slot)
         return out
 
     def run(self, *, max_steps: int = 100_000) -> list[Response]:
@@ -914,41 +809,6 @@ class Replica:
                             window=ctx["window"], action=ctx["action"],
                             code=ctx["code"], outcome="abandoned")
 
-    # ------------------------------------------------------------ decode path
-    def _decode_step(self) -> list[Response]:
-        self._step_count += 1
-        tokens, pos = self.sched.step_inputs()
-        mask = self.sched.active_mask()
-        logits, caches, words = self._decode(
-            self.params, self.caches, jnp.asarray(tokens), jnp.asarray(pos))
-        words = self._inject_words(words, (self.sched.num_slots,))
-        combined, count, table = self._enum(words, jnp.asarray(mask))
-        fut = DeviceFuture(outputs=(logits, caches), word=combined,
-                           count=count, table=table)
-        try:
-            logits, caches = fut.wait()
-            self._slot_logits, self.caches = logits, caches
-            return self._commit(skip=frozenset())
-        except PropagatedError as exc:
-            return self._recover(exc, fut)
-
-    def _commit(self, skip: frozenset[int]) -> list[Response]:
-        now = self.clock()
-        out = []
-        # argmax on device: ship S int32s to the host, not S×V logits
-        toks = np.asarray(jax.device_get(
-            jnp.argmax(self._slot_logits[:, 0, 0, :], axis=-1)))
-        committed = 0
-        for slot in self.sched.active_slots():
-            if slot in skip:
-                continue
-            resp = self.sched.commit_token(slot, int(toks[slot]), now)
-            committed += 1
-            if resp is not None:
-                out.append(resp)
-        self.metrics.record_step(committed)
-        return out
-
     # --------------------------------------------------------- window engine
     def _window_cycle(self) -> list[Response]:
         """Double-buffered commit loop: dispatch window N+1 from window N's
@@ -973,7 +833,7 @@ class Replica:
         # speculation: prompt feed rides the verify width, so one window can
         # consume up to K*(D+1) prompt tokens per lane
         chunk_width = (self.draft_len + 1) if self.speculate else 1
-        plan = (sched.plan_prefill(K * chunk_width) if self.overlap else {})
+        plan = sched.plan_prefill(K * chunk_width)
         if self.paged:
             # page maintenance first: lane restarts recycle their pages, every
             # writing lane gets growth pages, eviction preempts under pressure
@@ -984,7 +844,10 @@ class Replica:
         start_row = np.zeros(sched.num_slots, np.int64)
         rem0 = np.zeros(sched.num_slots, np.int64)
         deferred = np.zeros(sched.num_slots, bool)
-        extra = ((jnp.asarray(self.page_table),) if self.paged else ())
+        # jnp.array copies: on the CPU, jnp.asarray may alias a numpy buffer,
+        # and the host edits the table in place while this window is in
+        # flight (page reclaim, growth)
+        extra = ((jnp.array(self.page_table),) if self.paged else ())
         if self.tp > 1:
             # per-shard injection rides into the shard_mapped window as its
             # trailing (tp, K, S) operand: each shard ORs its slice into its
@@ -994,70 +857,63 @@ class Replica:
             if inj is None:
                 inj = np.zeros((self.tp, K, sched.num_slots), np.uint32)
             extra = extra + (jnp.asarray(inj),)
-        if self.overlap:
-            chunk = np.zeros((K, chunk_width, sched.num_slots), np.int32)
-            rem = np.zeros((sched.num_slots,), np.int32)
-            for slot, cp in plan.items():
-                if not sched.slots[slot].active:
-                    continue            # preempted by the page-pressure loop
-                if cp.rem == 0:
-                    # deferred fresh lane: no valid state yet — fully masked
-                    mask[slot] = 0
-                    start[slot] = K
-                    deferred[slot] = True
-                    continue
-                if cp.fresh and not self.paged:
-                    # lane (re)start: fresh cache slice + position 0, both
-                    # queued on the device chain — never a host sync (the
-                    # paged engine did this in _paged_prepare, plus the page
-                    # free/re-acquire/scrub that replaces the slab reset)
-                    self.caches = self._reset(self.caches, self._fresh,
-                                              jnp.int32(slot))
-                    self._set_dev_pos(slot, 0)
-                chunk.reshape(K * chunk_width,
-                              sched.num_slots)[:cp.rem, slot] = cp.tokens
-                rem[slot] = cp.rem
-                rem0[slot] = cp.rem
-                if cp.exhausts:
-                    # flip point: the argmax after the last prompt token is
-                    # the first committable token — step kf, verify row rf
-                    kf = (cp.rem - 1) // chunk_width
-                    start[slot] = kf
-                    start_row[slot] = (cp.rem - 1) - kf * chunk_width
-                else:
-                    start[slot] = K
-                self.metrics.record_chunk(cp.rem)
-                if self.trace.enabled:
-                    tr = sched.slots[slot].req.trace_id
-                    if tr is not None:
-                        self.trace.instant(
-                            "chunk", "prefill", ts=t_disp, tid=slot,
-                            trace_id=tr, slot=slot, tokens=cp.rem,
-                            fresh=cp.fresh, exhausts=cp.exhausts,
-                            window=self._step_count)
-            if not self.speculate:
-                chunk = chunk[:, 0, :]          # plain engines feed 1/step
-            if self.speculate:
-                # device-resident position chain: the per-window advance is
-                # data-dependent, so window N+1 reads window N's next_pos
-                # without the host ever seeing it
-                toks, counts, words, next_tok, next_pos, caches = (
-                    self._decode_window(
-                        self.params, self.caches, self._dev_tokens,
-                        self._dev_pos_dev, jnp.asarray(chunk),
-                        jnp.asarray(rem), *extra))
-                self._dev_pos_dev = next_pos
-                outputs = (toks, counts)
+        chunk = np.zeros((K, chunk_width, sched.num_slots), np.int32)
+        rem = np.zeros((sched.num_slots,), np.int32)
+        for slot, cp in plan.items():
+            if not sched.slots[slot].active:
+                continue            # preempted by the page-pressure loop
+            if cp.rem == 0:
+                # deferred fresh lane: no valid state yet — fully masked
+                mask[slot] = 0
+                start[slot] = K
+                deferred[slot] = True
+                continue
+            if cp.fresh and not self.paged:
+                # lane (re)start: fresh cache slice + position 0, both
+                # queued on the device chain — never a host sync (the
+                # paged engine did this in _paged_prepare, plus the page
+                # free/re-acquire/scrub that replaces the slab reset)
+                self.caches = self._reset(self.caches, self._fresh,
+                                          jnp.int32(slot))
+                self._set_dev_pos(slot, 0)
+            chunk.reshape(K * chunk_width,
+                          sched.num_slots)[:cp.rem, slot] = cp.tokens
+            rem[slot] = cp.rem
+            rem0[slot] = cp.rem
+            if cp.exhausts:
+                # flip point: the argmax after the last prompt token is
+                # the first committable token — step kf, verify row rf
+                kf = (cp.rem - 1) // chunk_width
+                start[slot] = kf
+                start_row[slot] = (cp.rem - 1) - kf * chunk_width
             else:
-                toks, words, *routed, next_tok, caches = self._decode_window(
+                start[slot] = K
+            self.metrics.record_chunk(cp.rem)
+            if self.trace.enabled:
+                tr = sched.slots[slot].req.trace_id
+                if tr is not None:
+                    self.trace.instant(
+                        "chunk", "prefill", ts=t_disp, tid=slot,
+                        trace_id=tr, slot=slot, tokens=cp.rem,
+                        fresh=cp.fresh, exhausts=cp.exhausts,
+                        window=self._step_count)
+        if self.speculate:
+            # device-resident position chain: the per-window advance is
+            # data-dependent, so window N+1 reads window N's next_pos
+            # without the host ever seeing it
+            toks, counts, words, next_tok, next_pos, caches = (
+                self._decode_window(
                     self.params, self.caches, self._dev_tokens,
-                    jnp.asarray(self._dev_pos), jnp.asarray(chunk),
-                    jnp.asarray(rem), *extra)
-                outputs = (toks, *routed) if routed else toks
+                    self._dev_pos_dev, jnp.asarray(chunk),
+                    jnp.asarray(rem), *extra))
+            self._dev_pos_dev = next_pos
+            outputs = (toks, counts)
         else:
+            # the plain window feeds one prompt token per step
             toks, words, *routed, next_tok, caches = self._decode_window(
                 self.params, self.caches, self._dev_tokens,
-                jnp.asarray(self._dev_pos), *extra)
+                jnp.asarray(self._dev_pos), jnp.asarray(chunk[:, 0, :]),
+                jnp.asarray(rem), *extra)
             outputs = (toks, *routed) if routed else toks
         # the device-side chain advances: window N+1 consumes these directly
         self.caches = caches
@@ -1295,7 +1151,7 @@ class Replica:
         # misses) are masked out, so a rejected draft never truncates the
         # clean committable prefix — and a real fault mid-speculation drops
         # every token from its step on (no stale draft tokens commit)
-        steps = win.fut.fault_steps(ignore=getattr(self, "_ignore_codes", 0))
+        steps = win.fut.fault_steps(ignore=self._ignore_codes)
         limits = np.full(num_slots, K, np.int64)
         for slot in faulted:
             limits[slot] = steps[slot] if steps is not None and steps[slot] >= 0 else 0
@@ -1390,198 +1246,15 @@ class Replica:
                 self._trace_recovery_begin(
                     slot, s.req.trace_id, word, decision.action.value,
                     win.index, self.clock())
-            resp = self._lflr_slot(slot)     # LFLR: recompute, don't restart
-            if resp is not None:
-                out.append(resp)
+            self._lflr_slot(slot)            # LFLR: recompute, don't restart
         return out
 
-    def _lflr_slot(self, slot: int) -> Optional[Response]:
-        """Window-mode LFLR recompute for one lane.
-
-        Overlapped: re-queue the lane — the scheduler chunks prompt +
-        committed tokens back into the cache through subsequent fused windows
-        (the cache reset rides the next dispatch), and the in-flight
-        speculative window's stale lane is invalidated. The host never blocks.
-        Blocking mode: the synchronous re-prefill."""
-        if not self.overlap:
-            return self._prefill_slot(slot)
+    def _lflr_slot(self, slot: int) -> None:
+        """LFLR recompute for one lane: re-queue it — the scheduler chunks
+        prompt + committed tokens back into the cache through subsequent
+        fused windows (the cache reset rides the next dispatch), and the
+        in-flight window's stale lane is invalidated. The host never
+        blocks."""
         self.sched.begin_prefill(slot)
         if self._pending is not None:
             self._pending.valid[slot] = False
-        return None
-
-    # --------------------------------------------------------------- recovery
-    def _recover(self, exc: PropagatedError, fut: DeviceFuture) -> list[Response]:
-        decision = self.policy.decide(exc, self._step_count)
-        num_slots = self.sched.num_slots
-        faulted = sorted({e.rank for e in exc.errors if 0 <= e.rank < num_slots})
-        if not faulted:                      # unattributed word: assume all
-            faulted = list(self.sched.active_slots())
-        self.metrics.record_fault(self._step_count, int(exc.combined_code),
-                                  decision.action.value, tuple(faulted))
-        slot_codes: dict[int, int] = {}
-        if self.trace.enabled:
-            # stepwise engine: no window history — the enumeration's
-            # per-(slot, code) pairs are the exact attribution
-            for e in exc.errors:
-                if 0 <= e.rank < num_slots:
-                    slot_codes[e.rank] = slot_codes.get(e.rank, 0) | int(e.code)
-            t_fault = self.clock()
-            for slot in faulted:
-                s = self.sched.slots[slot]
-                tr = s.req.trace_id if s.active else None
-                word = slot_codes.get(slot, int(exc.combined_code))
-                self.trace.instant(
-                    "fault", "fault", ts=t_fault, tid=slot, trace_id=tr,
-                    slot=slot, step=self._step_count, code=word,
-                    code_names=[c.name for c in ErrorCode(word).classes()],
-                    action=decision.action.value)
-        # Slots are independent under vmap: the dispatched outputs of the
-        # non-faulted slots are valid, so salvage them and only recompute the
-        # attributed ones — this is what keeps one bad sequence from stalling
-        # the whole batch.
-        self._slot_logits, self.caches = fut.outputs
-        if decision.action is Action.ROLLBACK:
-            # escalation: recompute every lane (whole-batch recompute is the
-            # serving analogue of restoring the last checkpoint)
-            targets, fail_now = list(self.sched.active_slots()), False
-        elif decision.action is Action.ABORT:
-            targets, fail_now = faulted, True
-        else:   # SKIP_BATCH / RESTORE_GOOD / CONTINUE / ... → per-sequence LFLR
-            targets, fail_now = faulted, False
-        out = self._commit(skip=frozenset(targets))
-        faulted_set = set(faulted)
-        for slot in targets:
-            if not self.sched.slots[slot].active:
-                continue                     # already evicted this cycle
-            # only the slots the enumeration attributed pay a retry: a healthy
-            # lane swept into a ROLLBACK recompute must not burn its budget
-            # (FAILED is reserved for requests that re-fault on recompute)
-            if slot in faulted_set:
-                retries = self.sched.note_retry(slot)
-            else:
-                retries = self.sched.request(slot).retries
-            if fail_now or retries > self.max_request_retries:
-                out.append(self.sched.evict(
-                    slot, FAILED,
-                    detail=f"{decision.reason} (retries={retries})"))
-                continue
-            if self.trace.enabled:
-                word = (slot_codes.get(slot, int(exc.combined_code))
-                        if slot in faulted_set else 0)
-                self._trace_recovery_begin(
-                    slot, self.sched.request(slot).trace_id, word,
-                    decision.action.value, self._step_count, self.clock())
-            resp = self._prefill_slot(slot)  # LFLR: recompute, don't restart
-            if resp is not None:
-                out.append(resp)
-        return out
-
-    # ---------------------------------------------------------------- prefill
-    def _prefill_slot(self, slot: int) -> Optional[Response]:
-        """*Blocking* (re-)compute of a slot's cache from its full token
-        history, committing the next token from the prefill logits. Serves
-        admission and the LFLR recompute on the stepwise and non-overlapped
-        window engines; the overlapped engine replaces it with background
-        lanes (``sched.begin_prefill`` + the fused window) and never blocks
-        here. The wall time spent inside — the host stall every healthy slot
-        pays — is recorded via ``metrics.record_host_stall``.
-
-        In (non-overlapped) window mode this is also the *patch point* of the
-        double-buffered pipeline: the rebuilt cache / next-token / position
-        overwrite the lane's device state (the in-flight speculative window's
-        outputs), and the lane is marked invalid in that window so its stale
-        block is skipped at retirement.
-
-        In paged mode the rebuilt cache is written straight into the slot's
-        (re-acquired, in-program-scrubbed) pool pages — there is no cache to
-        insert afterwards, only the device token feed to update."""
-        t0 = self.clock()
-        if self.trace.enabled:
-            # capture before commit: a finishing lane clears its slot
-            tr = self.sched.request(slot).trace_id
-            first_before = self.sched.slots[slot].t_first
-        try:
-            while True:
-                tokens = np.asarray([self.sched.sequence_tokens(slot)],
-                                    np.int32)
-                if self.paged:
-                    # recycle + reacquire the lane's pages for the full
-                    # sequence plus its first generated write position
-                    self._release_pages(slot)
-                    self._check_pages()
-                    if self._grow_slot(slot, tokens.shape[1] + 1,
-                                       exclude_self=True) is None:
-                        raise AssertionError("blocking prefill self-evicted")
-                    logits, hybrid, word = self._prefill(
-                        self.params, self.caches,
-                        jnp.asarray(self.page_table[slot]), jnp.int32(slot),
-                        tokens)
-                    # rebind NOW: the pool was donated to the dispatch, and a
-                    # faulted attempt's stray writes are confined to this
-                    # slot's row (drop-mode) and scrubbed by the retry's
-                    # in-program fresh_slot
-                    self.caches = hybrid
-                    fut = DeviceFuture(outputs=(logits, hybrid), word=word)
-                else:
-                    logits, cache, word = self._prefill(self.params, tokens,
-                                                        self.max_len)
-                    fut = DeviceFuture(outputs=(logits, cache), word=word)
-                try:
-                    logits, cache = fut.wait()
-                    break
-                except PropagatedError as exc:
-                    retries = self.sched.note_retry(slot)
-                    self.metrics.record_fault(self._step_count,
-                                              int(exc.combined_code),
-                                              "prefill_retry", (slot,))
-                    if self.trace.enabled:
-                        word = int(exc.combined_code)
-                        self.trace.instant(
-                            "fault", "fault", tid=slot, trace_id=tr,
-                            slot=slot, step=self._step_count, code=word,
-                            code_names=[c.name
-                                        for c in ErrorCode(word).classes()],
-                            action="prefill_retry")
-                        self._trace_recovery_begin(
-                            slot, tr, word, "prefill_retry",
-                            self._step_count, self.clock())
-                    if retries > self.max_request_retries:
-                        return self.sched.evict(
-                            slot, FAILED,
-                            detail=f"prefill faulted {retries} times: {exc}")
-            tok = int(jax.device_get(jnp.argmax(logits[0, -1])))
-            if self.paged:
-                # `cache` is the updated hybrid tree: pool writes landed
-                # through the page table, dense leaves at the slot slice
-                self.caches = cache
-                self._dev_tokens = self._set_tok(self._dev_tokens,
-                                                 jnp.int32(slot),
-                                                 jnp.int32(tok))
-            else:
-                self.caches, self._dev_tokens = self._insert(
-                    self.caches, cache, jnp.int32(slot), self._dev_tokens,
-                    jnp.int32(tok))
-            if not self.window:
-                # only the stepwise commit path reads logits back per slot
-                self._slot_logits = self._slot_logits.at[slot].set(
-                    logits.astype(jnp.float32))
-            t_commit = self.clock()
-            resp = self.sched.commit_token(slot, tok, t_commit)
-            self.metrics.record_prefill(1)
-            if self.trace.enabled and tr is not None:
-                self.trace.span("prefill", "prefill", t0, t_commit, tid=slot,
-                                trace_id=tr, slot=slot,
-                                tokens=int(tokens.shape[1]))
-                if first_before is None:
-                    self.trace.instant("first_token", "request", ts=t_commit,
-                                       tid=slot, trace_id=tr)
-                self._trace_recovery_end(slot, tr, t_commit, "recovered")
-            if self.window:
-                s = self.sched.slots[slot]
-                self._dev_pos[slot] = s.seq_len - 1 if s.active else 0
-                if self._pending is not None:
-                    self._pending.valid[slot] = False
-            return resp
-        finally:
-            self.metrics.record_host_stall(self.clock() - t0)

@@ -207,15 +207,15 @@ class ContinuousBatchingScheduler:
 
     The replica drives it in a strict step cycle::
 
-        expire_active → backfill (replica prefills the admitted slots)
-        → step_inputs → [fused decode on device] → commit_token per slot
+        expire_active → backfill → begin_prefill (admitted slots)
+        → plan_prefill → [decode window on device] → commit_block per slot
 
-    In window mode (``Replica(window=K)``) the cycle retires one K-token
-    decode window per step instead: ``commit_block`` consumes each lane's
-    token block up to EOS / budget / fault boundary and discards the trailing
-    tokens the deferred-detection window over-decoded.
+    ``commit_block`` consumes each lane's K-token block up to EOS / budget /
+    fault boundary and discards the trailing tokens the deferred-detection
+    window over-decoded.
 
-    On a fault, ``sequence_tokens``/``note_retry`` feed the LFLR recompute.
+    On a fault, ``note_retry`` + ``begin_prefill`` (which re-queues
+    ``sequence_tokens``) feed the LFLR recompute.
     """
 
     def __init__(self, num_slots: int, queue: RequestQueue, *,
@@ -378,30 +378,6 @@ class ContinuousBatchingScheduler:
         return admitted
 
     # ------------------------------------------------------------ step cycle
-    def step_inputs(self) -> tuple[np.ndarray, np.ndarray]:
-        """(tokens (S,1,1) int32, pos (S,) int32) for the fused decode step.
-
-        An active slot feeds its last token at its own absolute position; free
-        slots decode a dummy token at position 0 (their word is masked out and
-        their cache is overwritten at admission, so the work is dead weight the
-        fixed-shape batch pays for simplicity).
-        """
-        S = self.num_slots
-        tokens = np.zeros((S, 1, 1), np.int32)
-        pos = np.zeros((S,), np.int32)
-        for s in self.slots:
-            if not s.active:
-                continue
-            # The cache holds states for positions [0, seq_len-1): prefill
-            # consumed the prompt, decode consumed every generated token but
-            # the newest. The input is that newest token (the first one comes
-            # from the prefill logits, committed in Replica._prefill_slot, so
-            # active slots always have generated ≥ 1), at position seq_len-1.
-            last = s.generated[-1] if s.generated else s.req.prompt[-1]
-            tokens[s.idx, 0, 0] = last
-            pos[s.idx] = s.seq_len - 1
-        return tokens, pos
-
     def active_mask(self) -> np.ndarray:
         return np.asarray([1 if s.active else 0 for s in self.slots], np.uint32)
 

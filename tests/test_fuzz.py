@@ -279,10 +279,8 @@ class TestRunnerIntegration:
 
         kit = get_kit("overlap")
         rep = Replica(kit.cfg, params=kit.params,
-                      config=EngineConfig(num_slots=2, max_len=32, window=4,
-                                          overlap=True),
-                      decode_fn=kit.decode_fn, prefill_fn=kit.prefill_fn,
-                      window_fn=kit.window_fn,
+                      config=EngineConfig(num_slots=2, max_len=32, window=4),
+                      programs=kit.programs,
                       fault_injector=lambda i, shape: np.full(
                           shape, int(ErrorCode.DRAFT_REJECT), np.uint32))
         assert rep.submit(Request(id=0, prompt=(5, 6, 7),

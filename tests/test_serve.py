@@ -8,7 +8,7 @@ import pytest
 from repro.configs import smoke_config
 from repro.core.errors import ErrorCode
 from repro.core.faults import FaultSchedule, FaultSpec
-from repro.launch.steps import make_cache_prefill, make_slot_decode_step
+from repro.launch.steps import make_cache_prefill
 from repro.models import build_model
 from repro.serve import (
     EXPIRED,
@@ -23,7 +23,7 @@ from repro.serve import (
     RequestQueue,
     ServeGroup,
 )
-from repro.serve.replica import SERVE_PROBES
+from repro.serve.engine import SERVE_PROBES, build_engine
 
 
 class FakeClock:
@@ -101,9 +101,10 @@ def test_scheduler_backfills_freed_slot_after_eviction():
     # the freed slot is backfilled with the waiting request
     admitted = sched.backfill()
     assert [(s, r.id) for s, r in admitted] == [(0, 2)]
-    tokens, pos = sched.step_inputs()
-    assert tokens[1, 0, 0] == 200
-    assert pos[1] == 1 + 1 - 1               # prompt_len + generated - 1
+    # the mid-flight lane kept its progress: its next input is its newest
+    # token, at position prompt_len + generated - 1
+    s1 = sched.slots[1]
+    assert s1.generated == [200] and s1.seq_len - 1 == 1 + 1 - 1
 
 
 def test_scheduler_expires_active_sequence_mid_decode():
@@ -131,18 +132,22 @@ def test_scheduler_drain_in_flight_for_reroute():
 def serve_env():
     cfg = smoke_config("recurrentgemma-2b")
     params = build_model(cfg).init(jax.random.PRNGKey(0))
-    decode_fn = jax.jit(make_slot_decode_step(cfg, SERVE_PROBES))
     prefill_fn = make_cache_prefill(cfg, SERVE_PROBES)
-    return cfg, params, decode_fn, prefill_fn
+    return cfg, params, prefill_fn, {}
 
 
 def _replica(env, **kw):
-    cfg, params, decode_fn, prefill_fn = env
+    cfg, params, _, programs = env
     conf = {k: kw.pop(k) for k in list(kw) if k in EngineConfig.__dataclass_fields__}
     conf.setdefault("num_slots", 2)
     conf.setdefault("max_len", 48)
-    return Replica(cfg, params=params, config=EngineConfig(**conf),
-                   decode_fn=decode_fn, prefill_fn=prefill_fn, **kw)
+    conf = EngineConfig(**conf)
+    # one window program per shape, shared like a ServeGroup shares it
+    key = (conf.num_slots, conf.max_len, conf.window)
+    if key not in programs:
+        programs[key] = build_engine(cfg, params, conf)
+    return Replica(cfg, params=params, config=conf, programs=programs[key],
+                   **kw)
 
 
 def _serve_all(rep, reqs, inject_at=None):
@@ -175,9 +180,10 @@ def test_replica_serves_with_continuous_backfill(serve_env):
 
 
 def test_replica_lflr_reprefill_on_state_fault(serve_env):
-    clean = _serve_all(_replica(serve_env), _requests(2))
+    # 12 tokens over K=4 windows: slot 0 is still decoding at step 3
+    clean = _serve_all(_replica(serve_env), _requests(2, max_new=12))
     rep = _replica(serve_env)
-    faulty = _serve_all(rep, _requests(2), inject_at=3)
+    faulty = _serve_all(rep, _requests(2, max_new=12), inject_at=3)
     # the paper's contract: the fault became an exception and was recovered —
     # recompute (LFLR), not restart, so the trajectory is bit-identical
     assert faulty[0].status == OK and faulty[0].retries == 1
@@ -193,15 +199,17 @@ def test_replica_lflr_reprefill_on_state_fault(serve_env):
 def test_replica_fails_unrecoverable_request_without_wedging(serve_env):
     rep = _replica(serve_env, max_request_retries=1)
     doomed_mark = 499
-    orig = rep._prefill
+    real_win = rep._decode_window
 
-    def cursed_prefill(params, tokens, max_len, start_pos=0):
-        logits, cache, word = orig(params, tokens, max_len, start_pos)
-        if int(tokens[0][0]) == doomed_mark:   # this request always re-faults
-            word = word | jnp.uint32(int(ErrorCode.STATE_FAULT))
-        return logits, cache, word
+    def cursed_window(params, caches, *rest):
+        toks, words, next_tok, caches = real_win(params, caches, *rest)
+        for s in rep.sched.slots:              # this request always re-faults
+            if s.active and s.req.prompt[0] == doomed_mark:
+                words = words.at[0, s.idx].set(
+                    words[0, s.idx] | jnp.uint32(int(ErrorCode.STATE_FAULT)))
+        return toks, words, next_tok, caches
 
-    rep._prefill = cursed_prefill
+    rep._decode_window = cursed_window
     out = _serve_all(rep, [
         Request(id=0, prompt=(doomed_mark, 2, 3), max_new_tokens=4),
         Request(id=1, prompt=(7, 8, 9), max_new_tokens=4),
@@ -239,7 +247,7 @@ def test_replica_stops_at_eos(serve_env):
 
 def test_slot_decode_matches_single_sequence_prefill(serve_env):
     """The vmapped per-slot step must agree with the plain decode path."""
-    cfg, params, decode_fn, prefill_fn = serve_env
+    cfg, params, prefill_fn, _ = serve_env
     prompt = (11, 22, 33)
     rep = _replica(serve_env)
     out = _serve_all(rep, [Request(id=0, prompt=prompt, max_new_tokens=3)])

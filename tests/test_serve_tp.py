@@ -250,19 +250,6 @@ def test_fuzz_shard_targeted_op_round_trips_and_runs():
 
 # ----------------------------------------------------------------- EngineConfig
 class TestEngineConfig:
-    def test_cross_field_validation(self):
-        with pytest.raises(ValueError, match="tp>1 requires window"):
-            EngineConfig(tp=2)
-        with pytest.raises(ValueError, match="tp>1 requires overlap"):
-            EngineConfig(tp=2, window=4, overlap=False)
-        with pytest.raises(ValueError, match="paged=True requires window"):
-            EngineConfig(paged=True)
-        with pytest.raises(ValueError, match="speculate=True requires "
-                                             "overlap"):
-            EngineConfig(speculate=True, window=4, overlap=False)
-        with pytest.raises(ValueError, match="tp must be"):
-            EngineConfig(tp=0)
-
     def test_from_flags(self):
         c = EngineConfig.from_flags("win=8,spec=1,dlen=3,tp=2,page=16")
         assert (c.window, c.speculate, c.draft_len, c.tp) == (8, True, 3, 2)

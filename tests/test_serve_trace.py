@@ -15,6 +15,7 @@ from repro.obs import (
     NULL_TRACER,
     NullTracer,
     Tracer,
+    dump_trace,
     event_log_to_events,
     fault_report,
     group_chains,
@@ -142,7 +143,7 @@ def test_window_fault_events_bitmatch_error_words(env):
     # deferred detection surfaces, so a recovery lane actually opens
     out = _serve(rep, _requests(3, max_new=24), inject_at=3)
     assert all(r.status == OK for r in out.values())
-    records = [f for f in rep.metrics.faults if f.action != "prefill_retry"]
+    records = list(rep.metrics.faults)
     assert records
     trace = merge_traces(tr)
     assert validate(trace) == []
@@ -168,28 +169,6 @@ def test_window_fault_events_bitmatch_error_words(env):
                  and _args(fr.recovery)["outcome"] == "recovered"]
     assert recovered
     assert all(fr.recovery_s > 0 for fr in recovered)
-
-
-def test_stepwise_fault_events_bitmatch_enumeration(env):
-    """The stepwise engine has no window history: its fault events carry the
-    per-(slot, code) pairs of the paper's enumeration, OR-matching the
-    combined word."""
-    tr = Tracer()
-    rep = _replica(env, tr, window=0)
-    out = _serve(rep, _requests(3), inject_at=3)
-    assert all(r.status == OK for r in out.values())
-    records = [f for f in rep.metrics.faults if f.action != "prefill_retry"]
-    assert records
-    trace = merge_traces(tr)
-    assert validate(trace) == []
-    fault_evs = [e for e in trace["traceEvents"] if e["cat"] == "fault"
-                 and _args(e).get("action") == records[0].action]
-    assert fault_evs
-    word = 0
-    for e in fault_evs:
-        word |= _args(e)["code"]
-        assert _args(e)["step"] == records[0].step
-    assert word == records[0].code
 
 
 def test_paged_page_events_and_eviction_requeue():
@@ -247,7 +226,7 @@ def test_spec_draft_events_and_fault_word_strips_reject_bits():
         rep.metrics.draft_tokens
     assert sum(_args(e)["accepted"] for e in spec_evs) == \
         rep.metrics.accepted_draft_tokens
-    records = [f for f in rep.metrics.faults if f.action != "prefill_retry"]
+    records = list(rep.metrics.faults)
     assert records
     rec = records[0]
     fault_evs = [e for e in tr.events() if e["cat"] == "fault"
@@ -301,6 +280,49 @@ def test_group_kill_shrink_reroute_one_connected_trace():
     assert s["replicas"] == 3 and s["survivors"] == 2
     assert s["rerouted"] == len(res.rerouted)
     assert s["requests"] == 9 and s["statuses"] == {OK: 9}
+
+
+# ------------------------------------------------ trace_tool round trip
+@pytest.mark.parametrize("source", ["replica_fault", "group_kill"])
+def test_dumped_trace_passes_trace_tool_check(env, tmp_path, source):
+    """The post-mortem CLI re-validates a trace from disk: a faulted replica
+    run's dump and a replica kill's merged group dump both pass
+    ``scripts/trace_tool.py --check`` (exit 0), and a dump missing its
+    terminal spans fails it (exit 1)."""
+    import importlib.util
+    import json
+    import pathlib
+
+    spec = importlib.util.spec_from_file_location(
+        "trace_tool", pathlib.Path(__file__).parents[1] / "scripts"
+        / "trace_tool.py")
+    trace_tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(trace_tool)
+
+    path = tmp_path / "trace.json"
+    if source == "replica_fault":
+        tr = Tracer()
+        out = _serve(_replica(env, tr), _requests(4), inject_at=3)
+        assert all(r.status == OK for r in out.values())
+        trace = dump_trace(str(path), tr)
+        assert any(e["cat"] == "fault" for e in trace["traceEvents"])
+    else:
+        cfg, _ = env
+        group = ServeGroup(cfg, 2, config=EngineConfig(
+            num_slots=2, max_len=MAX_LEN, window=4, trace=True))
+        res = group.serve(
+            [Request(id=i, prompt=(5 + i, 6 + i, 7 + i), max_new_tokens=6)
+             for i in range(6)],
+            faults=FaultSchedule([FaultSpec(step=1, kind="kill", rank=1)]))
+        assert all(r.ok for r in res.responses.values())
+        trace = res.trace()
+        path.write_text(json.dumps(trace))
+    assert trace_tool.main([str(path), "--check"]) == 0
+    cut = {**trace, "traceEvents": [
+        e for e in trace["traceEvents"]
+        if not (e.get("cat") == "request" and e.get("name") == "request")]}
+    path.write_text(json.dumps(cut))
+    assert trace_tool.main([str(path), "--check"]) == 1
 
 
 # ----------------------------------------------- no-op tracer / sampling

@@ -1,17 +1,18 @@
-"""Zero-sync decode windows: token-identical to the stepwise path on clean
-traffic, bit-exact LFLR recovery from mid-window faults, EOS/budget boundary
-handling (trailing tokens discarded, lanes backfilled), and the host-sync
-budget (syncs scale with steps / K, not steps)."""
+"""Zero-sync decode windows: token-identical to the engine-independent
+greedy reference on clean traffic, bit-exact LFLR recovery from mid-window
+faults, EOS/budget boundary handling (trailing tokens discarded, lanes
+backfilled), the host-sync budget (syncs scale with steps / K, not steps),
+and the EngineConfig surface that builds the engine."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import count_syncs
+from conftest import count_syncs, greedy_reference
 
 from repro.configs import smoke_config
 from repro.core.errors import ErrorCode
-from repro.launch.steps import PerfOptions, make_cache_prefill
+from repro.launch.steps import make_cache_prefill
 from repro.models import build_model
 from repro.serve import FAILED, OK, EngineConfig, Replica, Request, ServeGroup
 from repro.serve.replica import SERVE_PROBES
@@ -55,28 +56,57 @@ def _serve_all(rep, reqs, inject_at=None):
 
 # ------------------------------------------------------------- clean traffic
 def test_window_decode_token_identical_to_stepwise(env):
-    """The K-step on-device scan must reproduce the per-token path exactly,
+    """The K-step on-device scan must reproduce per-token greedy decode
+    exactly (the engine-independent reference: a fresh prefill per token),
     including backfill chains (5 requests over 2 slots)."""
-    clean = _serve_all(_replica(env, 0), _requests(5))
+    cfg, params = env
+    clean = greedy_reference(cfg, params, _requests(5), MAX_LEN)
     for K in (1, 4, 8):
         rep = _replica(env, K)
         got = _serve_all(rep, _requests(5))
         assert sorted(got) == sorted(clean)
         for i in clean:
             assert got[i].status == OK
-            assert got[i].tokens == clean[i].tokens, (K, i)
+            assert got[i].tokens == clean[i], (K, i)
         m = rep.metrics
         assert m.windows > 0
         # every committed decode token came through a window, none per-token
         assert m.decode_tokens == sum(len(r.tokens) for r in got.values())
 
 
-def test_window_perf_options_knobs():
-    perf = PerfOptions.parse("window=8,donate=1")
-    assert perf.window == 8 and perf.donate is True
-    assert PerfOptions.parse("win=4,donate=0") == PerfOptions(
-        window=4, donate=False)
-    assert PerfOptions().window == 0        # stepwise default
+@pytest.mark.parametrize("flags,want", [
+    ("window=8", dict(window=8)),
+    ("win=4,overlap=1", dict(window=4, overlap=True)),
+    ("window=8,spec=1,dlen=4,dlayers=2",
+     dict(window=8, speculate=True, draft_len=4, draft_layers=2)),
+], ids=["window", "overlap", "spec"])
+def test_engine_config_from_flags(flags, want):
+    """``EngineConfig.from_flags`` is the one parser of engine-shape flag
+    strings."""
+    conf = EngineConfig.from_flags(flags)
+    assert conf == EngineConfig(**want)
+    for field, value in want.items():
+        assert getattr(conf, field) == value
+
+
+@pytest.mark.parametrize("kw,match", [
+    (dict(window=0), "window"),
+    (dict(overlap=False), "overlap"),
+    (dict(paged=True, window=0), "window"),
+    (dict(speculate=True, window=0), "window"),
+    (dict(speculate=True, window=8, overlap=False), "overlap"),
+    (dict(tp=2, window=0), "window"),
+    (dict(tp=2, window=4, overlap=False), "overlap"),
+    (dict(tp=0), "tp must be"),
+], ids=["window0", "overlap_off", "paged_window0", "spec_window0",
+        "spec_overlap_off", "tp_window0", "tp_overlap_off", "tp0"])
+def test_engine_config_rejects(kw, match):
+    """Construction fails at the config, in one place, for every owner
+    (Replica, ServeGroup, the fuzz kits): the stepwise (``window=0``) and
+    blocking-window (``overlap=False``) engines are gone, with or without
+    paging, speculation or tensor parallelism."""
+    with pytest.raises(ValueError, match=match):
+        EngineConfig(**kw)
 
 
 def test_fused_prefill_matches_loop_prefill(env):
@@ -103,15 +133,16 @@ def test_midwindow_fault_recovers_exact_trajectory(env, inject_at):
     """A STATE_FAULT latched mid-window is attributed to its exact (step,
     slot) at the boundary; LFLR re-prefill replays greedy from the last
     committed token, so the final trajectory is the fault-free one."""
-    clean = _serve_all(_replica(env, 0), _requests(2, max_new=14))
+    cfg, params = env
+    clean = greedy_reference(cfg, params, _requests(2, max_new=14), MAX_LEN)
     rep = _replica(env, 4)
     faulty = _serve_all(rep, _requests(2, max_new=14), inject_at=inject_at)
     assert faulty[0].status == OK and faulty[0].retries == 1
-    assert faulty[0].tokens == clean[0].tokens
+    assert faulty[0].tokens == clean[0]
     # deferred detection is still per-sequence: the co-batched lane committed
     # its whole window and never noticed
     assert faulty[1].status == OK and faulty[1].retries == 0
-    assert faulty[1].tokens == clean[1].tokens
+    assert faulty[1].tokens == clean[1]
     assert rep.metrics.fault_counts().get("STATE_FAULT") == 1
 
 
@@ -159,29 +190,29 @@ def test_window_group_kill_zero_dropped_requests(env):
 
 # ------------------------------------------------------- window boundaries
 def test_eos_midwindow_discards_trailing_and_backfills(env):
-    """EOS inside a window: the lane commits up to EOS, the over-decoded
-    trailing tokens are discarded, and the freed slot is backfilled at the
-    boundary. (Blocking engine: the injected EOS step index assumes the
-    window carries no prompt chunk; the overlapped equivalent lives in
-    test_serve_overlap.py.)"""
-    rep = _replica(env, 4, num_slots=2, eos_id=777, overlap=False)
+    """EOS inside a decode-only window: the lane commits up to EOS, the
+    over-decoded trailing tokens are discarded, and the freed slot is
+    backfilled at the boundary. (EOS in the window a lane flips from
+    prefill to decode is in test_serve_overlap.py.)"""
+    rep = _replica(env, 4, num_slots=2, eos_id=777)
     real_win = rep._decode_window
-    fired = []
+    dispatched = []
 
     def eos_at_step1(params, caches, tokens, pos, *chunk_args):
         toks, words, next_tok, caches = real_win(params, caches, tokens, pos,
                                                  *chunk_args)
-        if not fired:           # first dispatched window only
-            fired.append(True)
+        dispatched.append(True)
+        if len(dispatched) == 2:   # slot 0's first window with no chunk
             toks = toks.at[1, 0].set(777)   # slot 0 emits EOS at step 1
         return toks, words, next_tok, caches
 
     rep._decode_window = eos_at_step1
     out = _serve_all(rep, _requests(3, max_new=12))
     assert sorted(out) == [0, 1, 2]
-    # slot 0's request: prefill token + window step 0 + EOS, trailing dropped
+    # slot 0: window 1 feeds the 3-token prompt (steps 0-2) and commits
+    # steps 2-3; window 2 commits step 0 and the EOS, trailing dropped
     assert out[0].status == OK
-    assert out[0].tokens[-1] == 777 and len(out[0].tokens) == 3
+    assert out[0].tokens[-1] == 777 and len(out[0].tokens) == 4
     assert rep.metrics.discarded_tokens > 0
     # the freed lane was backfilled: the queued request completed in full
     assert out[2].status == OK and len(out[2].tokens) == 12
@@ -201,8 +232,8 @@ def test_budget_finish_midwindow_discards_trailing(env):
 # ---------------------------------------------------------- host-sync budget
 def test_host_sync_budget_scales_with_steps_over_K(env, monkeypatch):
     """Regression fence for the zero-sync contract: a serve run's host syncs
-    must scale with ``steps / K`` (+ one-off prefills), not with ``steps`` —
-    a future edit that sneaks a per-token readback back in fails this."""
+    must scale with ``steps / K``, not with ``steps`` — a future edit that
+    sneaks a per-token readback back in fails this."""
     reqs = lambda: _requests(4, max_new=16)  # noqa: E731
 
     def run(window):
@@ -210,17 +241,15 @@ def test_host_sync_budget_scales_with_steps_over_K(env, monkeypatch):
         return rep, _serve_all(rep, reqs())
 
     # warm the compiles outside the counted region
-    run(8), run(4), run(0)
+    run(8), run(4), run(1)
     syncs = {}
-    for K in (0, 4, 8):
+    for K in (1, 4, 8):
         syncs[K], (rep, out) = count_syncs(monkeypatch, lambda: run(K))
         assert all(r.status == OK for r in out.values())
-        if K:
-            m = rep.metrics
-            # ≤ 2 syncs per retired window (word + token block) and ≤ 2 per
-            # prefill (word + first-token argmax), plus slack for jit-internal
-            # transfers — nothing may scale per token.
-            assert syncs[K] <= 2 * m.windows + 2 * m.prefills + 4, (
-                K, syncs[K], m.windows, m.prefills)
-    # bigger windows → strictly fewer syncs; stepwise pays per token
-    assert syncs[8] < syncs[4] < syncs[0], syncs
+        m = rep.metrics
+        # ≤ 2 syncs per retired window (word + token block), plus slack for
+        # jit-internal transfers — nothing may scale per token, and
+        # admission (chunks inside the windows) costs no sync of its own.
+        assert syncs[K] <= 2 * m.windows + 4, (K, syncs[K], m.windows)
+    # bigger windows → strictly fewer syncs; a one-step window pays per token
+    assert syncs[8] < syncs[4] < syncs[1], syncs
